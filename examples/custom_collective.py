@@ -16,16 +16,19 @@ does not provide — entirely out of tree, with two algorithms:
 and shows the full stack working on it: selector pricing + auto choice,
 numpy-simulator validation against an oracle, and segmented execution.
 
-  python examples/custom_collective.py
+  JAX_PLATFORMS=cpu python examples/custom_collective.py
 """
 import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+if os.environ.get("JAX_PLATFORMS") == "cpu":  # 8 virtual host devices
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
 
 import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.launch import configure_compile_cache
 from repro.core import (
     CollectiveEngine, Communicator, Schedule, Sel, Step,
     register_collective, simulator,
@@ -90,6 +93,7 @@ def binomial_tree_scatter(comm: Communicator, root: int = 0) -> Schedule:
 
 
 def main():
+    configure_compile_cache()
     # -- register: this is ALL it takes to deploy a new collective ----------
     register_collective("scatter", linear_scatter, algorithm="linear",
                         protocols=("eager", "rendezvous"))

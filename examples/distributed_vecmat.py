@@ -10,11 +10,13 @@ end. `Sequencer.makespan` prices the drained queue — independent tile
 reductions overlap their per-hop latency on the shared link — against
 the serial sum of blocking `Program.cost`s.
 
-  python examples/distributed_vecmat.py
+  JAX_PLATFORMS=cpu python examples/distributed_vecmat.py
 """
 import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+if os.environ.get("JAX_PLATFORMS") == "cpu":  # 8 virtual host devices
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
 
 import time  # noqa: E402
 
@@ -23,6 +25,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
+from repro.launch import configure_compile_cache  # noqa: E402
 from repro.core import CollectiveEngine, Communicator  # noqa: E402
 from repro.core.hw_spec import ACCL_CLUSTER  # noqa: E402
 from repro.core.topology import make_mesh  # noqa: E402
@@ -31,6 +34,7 @@ TILES = 4  # output tiles in flight: tile t+1 computes while t drains
 
 
 def main():
+    configure_compile_cache()
     mesh = make_mesh((8,), ("x",))
     engine = CollectiveEngine(mesh, backend="microcode")
     rng = np.random.default_rng(0)

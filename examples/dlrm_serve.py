@@ -6,10 +6,15 @@ products travel through the collective engine. Serves batched requests and
 reports latency/throughput vs the single-device baseline.
 
   python examples/dlrm_serve.py --batches 20
+
+The model axis spans every device JAX sees unless --devices says fewer
+(with JAX_PLATFORMS=cpu: 8 virtual host devices).
 """
 import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
 
 import argparse  # noqa: E402
 import time  # noqa: E402
@@ -23,8 +28,8 @@ from repro.configs.base import ParallelConfig  # noqa: E402
 from repro.configs.dlrm import DLRMConfig  # noqa: E402
 from repro.core import CollectiveEngine  # noqa: E402
 from repro.core.topology import make_mesh  # noqa: E402
+from repro.launch import configure_compile_cache  # noqa: E402
 from repro.models import dlrm as dlrm_mod  # noqa: E402
-from repro.models.common import Builder  # noqa: E402
 from repro.parallel.ops import ParCtx  # noqa: E402
 
 
@@ -34,20 +39,22 @@ def main():
     ap.add_argument("--batch-size", type=int, default=32)
     ap.add_argument("--tables", type=int, default=32)
     ap.add_argument("--rows", type=int, default=50_000)
+    ap.add_argument("--devices", type=int, default=None)
     args = ap.parse_args()
+    configure_compile_cache()
 
+    n = args.devices or jax.device_count()
     cfg = DLRMConfig(n_tables=args.tables, emb_dim=32,
                      rows_per_table=args.rows, fc_dims=(2048, 512, 256))
-    mesh = make_mesh((1, 1, 8), ("pod", "data", "model"))
+    mesh = make_mesh((1, 1, n), ("pod", "data", "model"))
     engine = CollectiveEngine(mesh, backend="microcode")
     ctx = ParCtx(engine=engine, pcfg=ParallelConfig(), mesh=mesh)
 
-    b = Builder("init", key=jax.random.PRNGKey(0), dtype=jnp.float32)
-    params = dlrm_mod.dlrm_params(b, cfg, 8)
-    specs = dlrm_mod.dlrm_specs(cfg, 8)
+    params = dlrm_mod.dlrm_init(cfg, mesh, seed=0)
+    specs = dlrm_mod.dlrm_specs(cfg, n)
     emb_gb = args.tables * args.rows * 32 * 4 / 2**30
     print(f"tables: {args.tables} x {args.rows} rows "
-          f"({emb_gb:.2f} GiB embeddings, sharded 8-way)")
+          f"({emb_gb:.2f} GiB embeddings, sharded {n}-way)")
 
     serve = jax.jit(jax.shard_map(
         lambda p, i: dlrm_mod.dlrm_forward(p, i, ctx),

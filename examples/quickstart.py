@@ -2,21 +2,25 @@
 
 Runs on 8 virtual CPU devices — the ACCL+ simulation-platform analogue.
 
-  python examples/quickstart.py
+  JAX_PLATFORMS=cpu python examples/quickstart.py
 """
 import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+if os.environ.get("JAX_PLATFORMS") == "cpu":  # 8 virtual host devices
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
 
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.launch import configure_compile_cache
 from repro.core import CollectiveEngine, Communicator, Selector
 from repro.core.topology import make_mesh
 
 
 def main():
+    configure_compile_cache()
     mesh = make_mesh((8,), ("x",))
     engine = CollectiveEngine(mesh, backend="microcode")
 

@@ -5,15 +5,18 @@ fault-tolerant trainer, collective engine for every collective — on the
 8-virtual-device simulation mesh. The config is smollm-360m narrowed to
 ~100M params (depth/width cut, real vocab).
 
-  python examples/train_lm.py --steps 300
+  JAX_PLATFORMS=cpu python examples/train_lm.py --steps 300
 """
 import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+if os.environ.get("JAX_PLATFORMS") == "cpu":  # 8 virtual host devices
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
 
+from repro.launch import configure_compile_cache  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.configs.base import ParallelConfig  # noqa: E402
 from repro.core.topology import make_mesh  # noqa: E402
@@ -32,6 +35,7 @@ def lm_100m():
 
 
 def main():
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

@@ -16,7 +16,6 @@ Public API:
     Tracer/MetricsRegistry  unified telemetry (core/telemetry.py):
                          virtual-clock traces + the stats registry
 """
-from repro.core import compat  # installs the jax.shard_map polyfill first
 from repro.core.engine import CollectiveEngine, execute_program
 from repro.core.faults import (
     FaultPlan, FaultyTransport, PeerFailedError, ReliabilityTier, TIERS,
@@ -50,5 +49,5 @@ __all__ = [
     "Tracer", "NullTracer", "MetricsRegistry", "StatsView",
     "algorithms", "faults",
     "mesh_cost", "plugins", "pricing", "program", "sequencer", "simulator",
-    "telemetry", "compat",
+    "telemetry",
 ]

@@ -35,7 +35,6 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 from jax import lax
-from repro.core.compat import shard_map
 
 from repro.core import hierarchical, plugins, telemetry
 from repro.core.algorithms import GENERATORS
@@ -51,7 +50,7 @@ from repro.core.selector import Selector
 from repro.core.topology import (
     Communicator, ProductComm, axis_comm, product_comm,
 )
-from repro.core.hw_spec import HwSpec, TPU_V5E
+from repro.core.hw_spec import HwSpec, hw_for_devices
 
 
 # --------------------------------------------------------------------------
@@ -768,7 +767,8 @@ class CollectiveEngine:
 
     mesh: jax.sharding.Mesh
     backend: str = "microcode"
-    hw: HwSpec = TPU_V5E
+    # None: the HwSpec of the mesh's chips (`hw_for_devices`)
+    hw: Optional[HwSpec] = None
     selector: Selector = dataclasses.field(default_factory=Selector)
     use_pallas: bool = False
     # static-verifier level applied to every program this engine compiles
@@ -788,6 +788,10 @@ class CollectiveEngine:
     # lazily created request queue (core/sequencer.py) — the CCLO's
     # offload command queue behind the non-blocking `issue` API
     _queue: object = dataclasses.field(default=None, repr=False)
+
+    def __post_init__(self):
+        if self.hw is None:
+            self.hw = hw_for_devices(self.mesh.devices.flat)
 
     # -- infrastructure ------------------------------------------------------
     def comm(self, axis):
@@ -893,7 +897,7 @@ class CollectiveEngine:
 
     def run(self, fn, in_specs, out_specs):
         """shard_map wrapper for standalone (F2F-style) engine programs."""
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False))
 

@@ -68,6 +68,22 @@ ACCL_CLUSTER = HwSpec(
 
 TPU_V5E = HwSpec()
 
+# The TPUs this repo describes, keyed by JAX's `device.device_kind`.
+BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def hw_for_devices(devices) -> HwSpec:
+    """The HwSpec of the chips `devices` are: a TPU no HwSpec describes
+    is an error, never a default. The CPU's virtual devices stand in for
+    the modelled TPU_V5E."""
+    d = next(iter(devices))
+    if d.platform != "tpu":
+        return TPU_V5E
+    if d.device_kind not in BY_DEVICE_KIND:
+        raise ValueError(f"no HwSpec describes device kind "
+                         f"{d.device_kind!r}; known: {sorted(BY_DEVICE_KIND)}")
+    return BY_DEVICE_KIND[d.device_kind]
+
 
 def bytes_of(shape, dtype_bytes: int) -> int:
     n = 1

@@ -24,17 +24,11 @@ from repro.core.hw_spec import HwSpec, TPU_V5E
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Mesh constructor with stable axis_types across jax versions."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                tuple(shape), tuple(axes),
-                axis_types=(axis_type.Auto,) * len(tuple(axes)),
-            )
-        except TypeError:  # jax with AxisType but no axis_types kwarg
-            pass
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Mesh over the first prod(shape) devices, every axis Auto so
+    shard_map bodies see plain (non-explicit) sharding."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axes)))
 
 
 @dataclasses.dataclass(frozen=True)

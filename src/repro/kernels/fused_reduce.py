@@ -39,7 +39,7 @@ def _kernel(x_ref, y_ref, o_ref, *, op: str, acc_dtype):
                                              "interpret"))
 def fused_combine(x, y, *, op: str = "add", out_dtype=None,
                   block_rows: int = DEFAULT_BLOCK_ROWS,
-                  interpret: bool = True):
+                  interpret: bool):
     """Elementwise combine of two (rows, 128k)-shaped arrays.
 
     Accumulates in fp32 regardless of input dtype (the plugin's cast), then

@@ -17,12 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU memory-space hints; interpret mode accepts plain scratch too
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
@@ -46,7 +41,7 @@ def _kernel(x_ref, y_ref, o_ref, acc_ref):
                                              "interpret"))
 def matmul_tiled(x, y, *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
                  bk: int = DEFAULT_BK, out_dtype=None,
-                 interpret: bool = True):
+                 interpret: bool):
     """x: (M, K), y: (K, N); M % bm == K % bk == N % bn == 0 (ops.py pads)."""
     m, k = x.shape
     k2, n = y.shape
@@ -54,8 +49,6 @@ def matmul_tiled(x, y, *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, (m, n, k, bm, bn, bk)
     out_dtype = out_dtype or x.dtype
     grid = (m // bm, n // bn, k // bk)
-    scratch = [_VMEM((bm, bn), jnp.float32)] if _VMEM is not None else [
-        pl.BlockSpec(memory_space=None)]  # pragma: no cover
     return pl.pallas_call(
         _kernel,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
@@ -65,6 +58,6 @@ def matmul_tiled(x, y, *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
             pl.BlockSpec((bk, bn), lambda i, j, l: (l, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(x, y)

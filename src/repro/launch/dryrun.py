@@ -1,12 +1,14 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512" + (
     (" " + os.environ["XLA_FLAGS"]) if "XLA_FLAGS" in os.environ else "")
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST run before any other import (jax locks the device
-count on first init); 512 virtual host devices back the production meshes
-(16x16 single-pod, 2x16x16 multi-pod).
+A CPU-only tool. The lines above MUST run before any other import (jax
+locks the platform and device count on first init): it pins itself to the
+CPU, where 512 virtual host devices back the production meshes (16x16
+single-pod, 2x16x16 multi-pod).
 
 Per cell this produces, without allocating any real tensor:
   * compiled.memory_analysis()  -> bytes/device (fits-in-HBM check),
@@ -20,7 +22,8 @@ Usage:
   python -m repro.launch.dryrun --all [--multi-pod] [--jobs-file f.json]
 
 --all orchestrates one subprocess per cell (fresh XLA, resumable: cells
-with an existing result JSON are skipped).
+with an existing result JSON are skipped) and exits non-zero when a cell
+failed; a single cell exits non-zero when it failed.
 """
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
@@ -176,8 +179,6 @@ def _finish(result, lowered, chips, pod_size, model_flops, t_start):
     result["fits_hbm"] = result["memory"]["peak_bytes_est"] \
         < TPU_V5E.hbm_bytes
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x returns [dict]
-        cost = cost[0]
     text = compiled.as_text()
     hlo = analysis.analyze_hlo(text, pod_size)
     terms = analysis.roofline_terms(cost, mem, hlo, TPU_V5E, chips)
@@ -214,7 +215,6 @@ def run_dlrm_cell(multi_pod: bool, pcfg: ParallelConfig,
     from repro.models.common import Builder
     from repro.parallel.ops import ParCtx
     from repro.core.engine import CollectiveEngine
-    from repro.core.compat import shard_map
 
     t_start = time.time()
     mesh = make_production_mesh(multi_pod=multi_pod)
@@ -233,7 +233,7 @@ def run_dlrm_cell(multi_pod: bool, pcfg: ParallelConfig,
     pshapes = dlrm_mod.dlrm_params(b, dcfg, tp)
     dp = stages.dp_axes(mesh, batch)
     idx = sds((batch, dcfg.n_tables), jnp.int32, mesh, P(dp, None))
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda p, i: dlrm_mod.dlrm_forward(p, i, ctx),
         mesh=mesh, in_specs=(specs, P(dp, None)),
         out_specs=P(dp, None), check_vma=False))
@@ -315,7 +315,7 @@ def main():
         print(f"[dryrun] done; {len(failures)} failures")
         for n, e in failures:
             print("  FAIL", n, e)
-        return
+        sys.exit(1 if failures else 0)
 
     assert args.arch and (args.shape or args.arch == "dlrm"), \
         "--arch and --shape (or --all)"
@@ -340,6 +340,8 @@ def main():
                       if k not in ("traceback", "roofline")}, indent=1))
     if "roofline" in result:
         print(json.dumps(result["roofline"], indent=1))
+    if result["status"].startswith("FAIL"):
+        sys.exit(1)
 
 
 if __name__ == "__main__":

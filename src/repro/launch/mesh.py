@@ -8,32 +8,25 @@ from __future__ import annotations
 
 import jax
 
+from repro.core.topology import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False, tp: int = 16):
     """16x16 chips per pod; the multi-pod mesh adds a 2-pod DCN axis.
 
     `tp` retiles the same 256 chips/pod between the data and model axes
     (TP degree is a per-architecture tunable: small models want tp<=2,
-    MoE wants tp ~ expert granularity; see EXPERIMENTS §Perf)."""
+    MoE wants tp ~ expert granularity)."""
     per_pod = 256
     assert per_pod % tp == 0
     shape = (2, per_pod // tp, tp) if multi_pod else (per_pod // tp, tp)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except TypeError:  # older jax
-        return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh_for(devices: int, tp: int = None):
-    """Smoke/bench meshes on whatever devices exist (1..8 host CPUs)."""
+def make_mesh_for(devices: int | None = None, tp: int | None = None):
+    """A (1, dp, tp) smoke/serving mesh over the first `devices` devices
+    (default: every device JAX sees)."""
+    devices = devices or jax.device_count()
     tp = tp or (2 if devices % 2 == 0 else 1)
-    dp = devices // tp
-    try:
-        return jax.make_mesh(
-            (1, dp, tp), ("pod", "data", "model"),
-            axis_types=(jax.sharding.AxisType.Auto,) * 3)
-    except TypeError:
-        return jax.make_mesh((1, dp, tp), ("pod", "data", "model"))
+    return make_mesh((1, devices // tp, tp), ("pod", "data", "model"))

@@ -1,7 +1,11 @@
 """Serving launcher: prefill + greedy decode over the sharded caches.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b \
-      --prompt-len 16 --gen 8 --devices 8
+      --prompt-len 16 --gen 8
+
+It serves on every device JAX sees unless --devices says fewer; with
+JAX_PLATFORMS=cpu, --devices N makes N virtual host devices. --full
+serves the published widths instead of the reduced config.
 """
 import argparse
 import os
@@ -13,35 +17,39 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=8)
-    ap.add_argument("--devices", type=int, default=8)
-    ap.add_argument("--tp", type=int, default=2)
+    ap.add_argument("--devices", type=int, default=None)
+    ap.add_argument("--tp", type=int, default=None)
     ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--full", dest="reduced", action="store_false")
     ap.add_argument("--backend", default="microcode")
     args = ap.parse_args()
 
-    os.environ.setdefault(
-        "XLA_FLAGS",
-        f"--xla_force_host_platform_device_count={args.devices}")
+    if args.devices and os.environ.get("JAX_PLATFORMS") == "cpu":
+        os.environ.setdefault(
+            "XLA_FLAGS",
+            f"--xla_force_host_platform_device_count={args.devices}")
 
     import jax.numpy as jnp
     import numpy as np
 
     from repro.configs import get_config, reduced_config
     from repro.configs.base import ParallelConfig
-    from repro.launch.mesh import make_mesh_for
+    from repro.launch import configure_compile_cache, make_mesh_for
     from repro.parallel import stages
 
+    configure_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced_config(cfg)
     mesh = make_mesh_for(args.devices, tp=args.tp)
+    tp = mesh.shape["model"]
     pcfg = ParallelConfig(backend=args.backend,
                           moe_capacity_factor=8.0)
     s_max = args.prompt_len + args.gen
-    params = stages.init_params(cfg, mesh, args.tp, seed=0)
+    params = stages.init_params(cfg, mesh, tp, seed=0)
     dstep, _, _, _ = stages.build_decode_step(
         cfg, pcfg, mesh, s_max=s_max, global_batch=args.batch)
-    cache = stages.init_cache(cfg, pcfg, mesh, args.tp, args.batch, s_max)
+    cache = stages.init_cache(cfg, pcfg, mesh, tp, args.batch, s_max)
 
     rng = np.random.default_rng(0)
     prompt = rng.integers(0, cfg.vocab_size,

@@ -1,11 +1,12 @@
 """Production training launcher.
 
   PYTHONPATH=src python -m repro.launch.train --arch smollm-360m \
-      --steps 100 --batch 8 --seq 64 --devices 8
+      --steps 100 --batch 8 --seq 64
 
-On a real pod this process runs per host (jax.distributed.initialize is
-called when JAX_COORDINATOR is set); in this container it runs on virtual
-host devices. Arch/shape/parallelism knobs mirror the dry-run's.
+It runs on every device JAX sees unless --devices says fewer. On a pod
+this process runs per host (jax.distributed.initialize is called when
+JAX_COORDINATOR is set). With JAX_PLATFORMS=cpu, --devices N makes N
+virtual host devices. Arch/shape/parallelism knobs mirror the dry-run's.
 """
 import argparse
 import os
@@ -17,8 +18,8 @@ def main():
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
-    ap.add_argument("--devices", type=int, default=8)
-    ap.add_argument("--tp", type=int, default=2)
+    ap.add_argument("--devices", type=int, default=None)
+    ap.add_argument("--tp", type=int, default=None)
     ap.add_argument("--reduced", action="store_true", default=True)
     ap.add_argument("--full", dest="reduced", action="store_false")
     ap.add_argument("--backend", default="microcode")
@@ -31,9 +32,10 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    os.environ.setdefault(
-        "XLA_FLAGS",
-        f"--xla_force_host_platform_device_count={args.devices}")
+    if args.devices and os.environ.get("JAX_PLATFORMS") == "cpu":
+        os.environ.setdefault(
+            "XLA_FLAGS",
+            f"--xla_force_host_platform_device_count={args.devices}")
     if os.environ.get("JAX_COORDINATOR"):
         import jax
         jax.distributed.initialize()  # multi-host pod entry point
@@ -41,11 +43,12 @@ def main():
     from repro.configs import get_config, reduced_config
     from repro.configs.base import ParallelConfig
     from repro.data import DataConfig
-    from repro.launch.mesh import make_mesh_for
+    from repro.launch import configure_compile_cache, make_mesh_for
     from repro.optim import adamw
     from repro.optim.schedules import cosine_warmup
     from repro.runtime import Trainer, TrainerConfig
 
+    configure_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced_config(cfg)

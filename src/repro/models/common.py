@@ -1,7 +1,8 @@
 """Shared model machinery: param builder, norms, rope, activations.
 
 The `Builder` gives every layer a single definition that can produce
-  mode='init'   real initialized jnp arrays (smoke tests, examples),
+  mode='init'   real initialized jnp arrays (smoke tests, examples;
+                `sharded_initializer` runs it under jit, into shards),
   mode='spec'   a PartitionSpec pytree (shard_map in_specs, checkpointing),
   mode='shape'  ShapeDtypeStructs with NamedSharding (the dry-run: no
                 allocation ever happens for the 26B configs).
@@ -69,6 +70,17 @@ class Builder:
             u = jax.random.uniform(k, shape, jnp.float32, 1e-3, 1e-1)
             return jnp.log(jnp.expm1(u)).astype(jnp.float32)
         raise ValueError(init)
+
+
+def sharded_initializer(build, mesh, specs, dtype=jnp.float32):
+    """jit of key -> `build(Builder("init"))`, each leaf created in place
+    with the NamedSharding of its spec: every device initialises only its
+    own shard, so no whole parameter (or RNG temporary) lands on one
+    device."""
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                             is_leaf=lambda x: isinstance(x, P))
+    return jax.jit(lambda key: build(Builder("init", key=key, dtype=dtype)),
+                   out_shardings=shardings)
 
 
 # --------------------------------------------------------------------------

@@ -27,13 +27,20 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.dlrm import DLRMConfig
-from repro.models.common import Builder
+from repro.models.common import Builder, sharded_initializer
 from repro.parallel.ops import ParCtx
+
+# Rows per shard are a multiple of this, so the Pallas lookup reads every
+# shard in place (kernels/embedding_gather.py: V on the 128 lanes).
+ROW_ALIGN = 128
 
 
 def dlrm_params(b: Builder, cfg: DLRMConfig, tp: int):
-    """Tables stacked (T, rows, dim) sharded over model on rows."""
-    rows = ((cfg.rows_per_table + tp - 1) // tp) * tp
+    """Tables stacked (T, rows, dim) sharded over model on rows; rows is
+    rows_per_table rounded up to ROW_ALIGN per shard (ids stay below
+    rows_per_table, so the tail rows are never looked up)."""
+    align = tp * ROW_ALIGN
+    rows = -(-cfg.rows_per_table // align) * align
     concat = cfg.n_tables * cfg.emb_dim
     p = {
         "tables": b.param((cfg.n_tables, rows, cfg.emb_dim),
@@ -64,6 +71,18 @@ def dlrm_specs(cfg: DLRMConfig, tp: int):
     return dlrm_params(Builder("spec"), cfg, tp)
 
 
+def dlrm_initializer(cfg: DLRMConfig, mesh):
+    """jit of PRNG key -> random parameters, made on the mesh shard by
+    shard (`models.common.sharded_initializer`)."""
+    tp = mesh.shape["model"]
+    return sharded_initializer(lambda b: dlrm_params(b, cfg, tp), mesh,
+                               dlrm_specs(cfg, tp))
+
+
+def dlrm_init(cfg: DLRMConfig, mesh, seed: int = 0):
+    return dlrm_initializer(cfg, mesh)(jax.random.PRNGKey(seed))
+
+
 def embedding_lookup(tables, indices, ctx: ParCtx, use_pallas: bool = False):
     """tables: (T, rows_local, dim) local slice over 'model'; indices:
     (B, T) global row ids. Returns (B, T*dim) concat vector, replicated.
@@ -80,8 +99,7 @@ def embedding_lookup(tables, indices, ctx: ParCtx, use_pallas: bool = False):
     safe = jnp.clip(local, 0, rows_l - 1)
     if use_pallas:
         from repro.kernels import ops as kops
-        rows = jnp.stack([
-            kops.embedding_gather(tables[i], safe[i]) for i in range(t)])
+        rows = kops.embedding_gather(tables, safe)  # (T, B, dim)
     else:
         rows = jax.vmap(lambda tab, ix: jnp.take(tab, ix, axis=0))(
             tables, safe)                         # (T, B, dim)
@@ -124,12 +142,17 @@ def dlrm_forward(params, indices, ctx: ParCtx, use_pallas: bool = False):
 
 def dlrm_reference(params_full, indices):
     """Single-device oracle on gathered params (tests)."""
-    t = params_full["tables"].shape[0]
-    rows = jnp.stack([params_full["tables"][i][indices[:, i]]
-                      for i in range(t)])
-    x = jnp.moveaxis(rows, 0, 1).reshape(indices.shape[0], -1)
-    n = len(params_full["fc"])
-    for i, fc in enumerate(params_full["fc"]):
+    tables = params_full["tables"]
+    rows = tables[jnp.arange(tables.shape[0])[None, :], indices]  # (B,T,d)
+    return dlrm_mlp_reference(params_full["fc"],
+                              rows.reshape(indices.shape[0], -1))
+
+
+def dlrm_mlp_reference(fc_params, x):
+    """The FC stack on already looked-up concat vectors (B, T*dim): the
+    oracle for tables that fit on no single device."""
+    n = len(fc_params)
+    for i, fc in enumerate(fc_params):
         x = x @ fc["w"] + fc["b"]
         if i < n - 1:
             x = jax.nn.relu(x)

@@ -23,14 +23,13 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from repro.core.compat import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ParallelConfig
 from repro.core.engine import CollectiveEngine
 from repro.models import lm as lm_mod
 from repro.models import serve as serve_mod
-from repro.models.common import Builder, dt
+from repro.models.common import Builder, dt, sharded_initializer
 from repro.optim import adamw
 from repro.parallel.ops import ParCtx, spec_axes
 
@@ -75,14 +74,10 @@ def param_shapes(cfg: ArchConfig, mesh, tp: int, dtype=None,
 
 
 def init_params(cfg: ArchConfig, mesh, tp: int, seed: int = 0):
-    """Real init (host-side, then device_put with the spec sharding)."""
-    b = Builder("init", key=jax.random.PRNGKey(seed),
-                dtype=dt(cfg.param_dtype))
-    params = lm_mod.model_params(b, cfg, tp)
-    specs = param_specs(cfg, tp)
-    return jax.tree.map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
-        params, specs, is_leaf=lambda x: isinstance(x, jnp.ndarray))
+    """Real init, under jit, straight into the spec shardings."""
+    init = sharded_initializer(lambda b: lm_mod.model_params(b, cfg, tp),
+                               mesh, param_specs(cfg, tp), dt(cfg.param_dtype))
+    return init(jax.random.PRNGKey(seed))
 
 
 # --------------------------------------------------------------------------
@@ -249,7 +244,7 @@ def build_train_step(cfg: ArchConfig, pcfg: ParallelConfig, mesh,
         metrics["loss"] = loss
         return params, opt_state, metrics
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(specs, ospecs, bspec, P()),
         out_specs=(specs, ospecs, jax.tree.map(lambda _: P(), {
@@ -287,7 +282,7 @@ def build_prefill(cfg: ArchConfig, pcfg: ParallelConfig, mesh,
     def pf(params, batch):
         return serve_mod.prefill(params, batch, cfg, ctx)
 
-    mapped = shard_map(pf, mesh=mesh, in_specs=(specs, bspec),
+    mapped = jax.shard_map(pf, mesh=mesh, in_specs=(specs, bspec),
                        out_specs=(P(dp), cspec), check_vma=False)
     return jax.jit(mapped), ctx, specs, bspec
 
@@ -331,7 +326,7 @@ def build_decode_step(cfg: ArchConfig, pcfg: ParallelConfig, mesh,
         return serve_mod.decode_step(params, caches, tokens, pos, cfg, ctx,
                                      s_max)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         dstep, mesh=mesh,
         in_specs=(specs, cspecs, P(dp, None), P()),
         out_specs=(P(dp), cspecs),

@@ -15,8 +15,6 @@ def test_loop_free_matches_cost_analysis():
         jax.ShapeDtypeStruct((128, 64), jnp.float32)).compile()
     st = analyze_hlo(c.as_text())
     ca = c.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x returns [dict]
-        ca = ca[0]
     assert st.flops == float(ca["flops"]) == 2 * 256 * 128 * 64
 
 

@@ -21,7 +21,7 @@ from repro.core.faults import (
 )
 from repro.core.hw_spec import ACCL_CLUSTER
 from repro.core.program import fit_segments
-from tests._hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 
 @pytest.fixture(scope="module")

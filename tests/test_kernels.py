@@ -3,7 +3,7 @@ with hypothesis property tests where invariants exist."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 
@@ -13,7 +13,7 @@ from repro.kernels import ops, ref
 def test_fused_add(rng, shape, dtype):
     x = jnp.asarray(rng.normal(size=shape), dtype)
     y = jnp.asarray(rng.normal(size=shape), dtype)
-    out = ops.fused_add(x, y)
+    out = ops.fused_add(x, y, interpret=True)
     np.testing.assert_allclose(
         np.asarray(out, np.float32),
         np.asarray(ref.fused_combine(x, y), np.float32), atol=1e-2)
@@ -23,7 +23,7 @@ def test_fused_add(rng, shape, dtype):
 def test_fused_combine_ops(rng, op):
     x = jnp.asarray(rng.normal(size=(257, 3)), jnp.float32)
     y = jnp.asarray(rng.normal(size=(257, 3)), jnp.float32)
-    out = ops.fused_combine(x, y, op=op)
+    out = ops.fused_combine(x, y, op=op, interpret=True)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref.fused_combine(x, y, op)),
                                atol=1e-5)
@@ -32,9 +32,9 @@ def test_fused_combine_ops(rng, op):
 @pytest.mark.parametrize("n", [256, 1000, 100_000])
 def test_quantize_roundtrip(rng, n):
     flat = jnp.asarray(rng.normal(size=(n,)) * 13, jnp.float32)
-    q, s = ops.quantize_int8(flat)
+    q, s = ops.quantize_int8(flat, interpret=True)
     assert q.dtype == jnp.int8
-    back = np.asarray(ops.dequantize_int8(q, s))[:n]
+    back = np.asarray(ops.dequantize_int8(q, s, interpret=True))[:n]
     rel = np.abs(back - np.asarray(flat)).max() / (
         np.abs(np.asarray(flat)).max() + 1e-9)
     assert rel < 0.01
@@ -48,8 +48,8 @@ def test_quantize_scale_invariance(scale, seed):
     scale exactly."""
     r = np.random.default_rng(seed)
     flat = jnp.asarray(r.normal(size=(512,)), jnp.float32)
-    q1, s1 = ops.quantize_int8(flat)
-    q2, s2 = ops.quantize_int8(flat * scale)
+    q1, s1 = ops.quantize_int8(flat, interpret=True)
+    q2, s2 = ops.quantize_int8(flat * scale, interpret=True)
     diff = np.abs(np.asarray(q1, np.int32)[:512]
                   - np.asarray(q2, np.int32)[:512])
     assert diff.max() <= 1, diff.max()
@@ -66,7 +66,7 @@ def test_quantize_scale_invariance(scale, seed):
 def test_matmul(rng, m, k, n, dtype):
     a = jnp.asarray(rng.normal(size=(m, k)), dtype)
     b = jnp.asarray(rng.normal(size=(k, n)), dtype)
-    out = ops.matmul(a, b)
+    out = ops.matmul(a, b, interpret=True)
     expect = ref.matmul(a, b)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32),
@@ -79,7 +79,7 @@ def test_matmul(rng, m, k, n, dtype):
 def test_embedding_gather(rng, v, d, b):
     table = jnp.asarray(rng.normal(size=(v, d)), jnp.float32)
     idx = jnp.asarray(rng.integers(0, v, size=(b,)), jnp.int32)
-    out = ops.embedding_gather(table, idx)
+    out = ops.embedding_gather(table, idx, interpret=True)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref.gather_rows(table, idx)))
 
@@ -96,3 +96,15 @@ def test_vmem_block_alignment():
     assert ws < TPU_V5E.vmem_bytes
     for d in (mm.DEFAULT_BM, mm.DEFAULT_BN, mm.DEFAULT_BK):
         assert d % 128 == 0
+
+
+@pytest.mark.parametrize("t,v,d,b", [(3, 256, 32, 300), (2, 1000, 32, 128),
+                                     (4, 384, 16, 7)])
+def test_embedding_gather_stack(rng, t, v, d, b):
+    """(T, V, D) table stacks, one id list per table — the DLRM lookup."""
+    tables = jnp.asarray(rng.normal(size=(t, v, d)), jnp.float32)
+    idx = jnp.asarray(rng.integers(0, v, size=(t, b)), jnp.int32)
+    out = ops.embedding_gather(tables, idx, interpret=True)
+    want = np.stack([np.asarray(ref.gather_rows(tables[i], idx[i]))
+                     for i in range(t)])
+    np.testing.assert_array_equal(np.asarray(out), want)

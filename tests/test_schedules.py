@@ -2,7 +2,7 @@
 hypothesis property tests on schedule structure."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import algorithms as A
 from repro.core.simulator import oracle, simulate

@@ -14,7 +14,7 @@ from repro.core import (
     register_collective, unregister_collective, simulator,
 )
 from repro.core.sequencer import Sequencer
-from tests._hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 
 @pytest.fixture(scope="module")
