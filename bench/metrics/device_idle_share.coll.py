@@ -1,0 +1,4 @@
+"""Share of the traced window in which no operation ran on the device
+(%), averaged over the chips: what dispatch between collective programs
+leaves idle."""
+from bench.trace import idle_share as read  # noqa: F401
