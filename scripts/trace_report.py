@@ -87,7 +87,7 @@ def summarize(events: list, top: int = 10) -> dict:
                         "status": a.get("status"),
                     })
         elif pid == CONTROL_PID:
-            kind = {"X": "span", "i": "instant", "C": "counter"}.get(ph)
+            kind = {"X": "span", "i": "instant"}.get(ph)
             if kind is not None:
                 key = f"{kind}:{ev.get('name')}"
                 control[key] = control.get(key, 0) + 1

@@ -14,7 +14,9 @@ Public API:
     FaultPlan/ReliabilityTier  fabric fault model + protocol tiers
     register_collective  out-of-tree collectives, no engine changes needed
     Tracer/MetricsRegistry  unified telemetry (core/telemetry.py):
-                         virtual-clock traces + the stats registry
+                         wall-clock control-plane spans on the
+                         profiler's clock, virtual-clock traces, and
+                         the stats registry
 """
 from repro.core.engine import CollectiveEngine, execute_program
 from repro.core.faults import (

@@ -95,6 +95,7 @@ def _recv_region(buf, chunks: int, sel: Sel, rank, s_idx):
     return _select(buf, chunks, sel, rank, s_idx), off, None
 
 
+@telemetry.named_scope("uop.combine")
 def _apply_write(buf, chunks: int, off, mask_idxs, new_val):
     """Write a combined region value back (inverse of `_recv_region`)."""
     if mask_idxs is not None:
@@ -171,8 +172,9 @@ def _send_chain(send_ops: tuple, seg, axis, use_pallas: bool):
     cur = seg
     for op in send_ops:
         if isinstance(op, Compress):
-            cur = plugins.get_codec(op.codec).compress(
-                cur, use_pallas=use_pallas)
+            with jax.named_scope("uop.codec"):
+                cur = plugins.get_codec(op.codec).compress(
+                    cur, use_pallas=use_pallas)
         elif isinstance(op, Send):
             ax, perm = _send_axis(op, axis)
             cur = jax.tree.map(
@@ -187,8 +189,9 @@ def _recv_chain(dec_ops: tuple, wire, shape, dtype, use_pallas: bool):
     cur = wire
     for op in dec_ops:
         if isinstance(op, Decompress):
-            cur = plugins.get_codec(op.codec).decompress(
-                cur, shape, dtype, use_pallas=use_pallas)
+            with jax.named_scope("uop.codec"):
+                cur = plugins.get_codec(op.codec).decompress(
+                    cur, shape, dtype, use_pallas=use_pallas)
         else:
             raise ValueError(f"bad recv op {op}")
     return cur
@@ -288,7 +291,8 @@ def _exchange_update(body: tuple, k_req: int, buf, orig, prev, chunks: int,
     def consume(i, wire):
         inc = _recv_chain(dec_ops, wire, seg_shape, payload.dtype,
                           use_pallas)
-        out = comb(tgt[i], inc.astype(buf.dtype))
+        with jax.named_scope("uop.combine"):
+            out = comb(tgt[i], inc.astype(buf.dtype))
         return (out, inc) if recv.track_recv else out
 
     new_val, raw = _pipelined_exchange(payload, send, consume, k,
@@ -411,11 +415,13 @@ def _exec_stream(st: Stream, buf, orig, prev, chunks: int, nranks: int,
         tgt = lax.dynamic_slice_in_dim(b, off, seg_len, 0)
         inc = _recv_chain(dec_ops, wire, (seg_len,) + b.shape[1:], dtype,
                           use_pallas)
-        out = plugins.combine(recv.op, tgt, inc.astype(dtype),
-                              use_pallas=use_pallas)
-        b = lax.dynamic_update_slice_in_dim(b, out, off, 0)
-        if recv.track_recv:
-            pv = lax.dynamic_update_slice_in_dim(pv, inc, j * seg_len, 0)
+        with jax.named_scope("uop.combine"):
+            out = plugins.combine(recv.op, tgt, inc.astype(dtype),
+                                  use_pallas=use_pallas)
+            b = lax.dynamic_update_slice_in_dim(b, out, off, 0)
+            if recv.track_recv:
+                pv = lax.dynamic_update_slice_in_dim(pv, inc, j * seg_len,
+                                                     0)
         return b, pv
 
     nslots = len(parts)
@@ -535,9 +541,10 @@ def _exec_chain(ch: StreamChain, buf, orig, prev, chunks: int, nranks: int,
         tgt = lax.dynamic_slice_in_dim(b, off, seg, 0)
         inc = _recv_chain(dec_ops, wire, (seg,) + b.shape[1:], dtype,
                           use_pallas)
-        out = plugins.combine(recv.op, tgt, inc.astype(dtype),
-                              use_pallas=use_pallas)
-        return lax.dynamic_update_slice_in_dim(b, out, off, 0)
+        with jax.named_scope("uop.combine"):
+            out = plugins.combine(recv.op, tgt, inc.astype(dtype),
+                                  use_pallas=use_pallas)
+            return lax.dynamic_update_slice_in_dim(b, out, off, 0)
 
     inflight = send_wave(buf, *waves[0])
     for w, (s, j) in enumerate(waves):
@@ -599,30 +606,39 @@ def execute_program(prog: Program, buf, axis, *,
     ops = prog.ops
     i = 0
     if ops and isinstance(ops[0], Copy) and ops[0].kind == "bruck_pre":
-        buf = _chunk_roll(buf, prog.chunks, -rank)
+        with jax.named_scope("uop.rotate"):
+            buf = _chunk_roll(buf, prog.chunks, -rank)
         i = 1
     orig = buf
     prev = buf  # relay='received': step 0 forwards the original input
 
+    # each micro-op runs under the device scope `uop.<kind>`; inside it
+    # codec and combine work carry `uop.codec` / `uop.combine`
     while i < len(ops):
         op = ops[i]
         if isinstance(op, Loop):
-            buf, prev = _exec_loop(op, buf, orig, prev, prog.chunks, rank,
-                                   axis, use_pallas)
+            with jax.named_scope("uop.loop"):
+                buf, prev = _exec_loop(op, buf, orig, prev, prog.chunks,
+                                       rank, axis, use_pallas)
             i += 1
         elif isinstance(op, Stream):
-            buf, prev = _exec_stream(op, buf, orig, prev, prog.chunks,
-                                     prog.nranks, rank, axis, use_pallas)
+            with jax.named_scope("uop.stream"):
+                buf, prev = _exec_stream(op, buf, orig, prev, prog.chunks,
+                                         prog.nranks, rank, axis,
+                                         use_pallas)
             i += 1
         elif isinstance(op, StreamChain):
-            buf = _exec_chain(op, buf, orig, prev, prog.chunks,
-                              prog.nranks, rank, axis, use_pallas)
+            with jax.named_scope("uop.chain"):
+                buf = _exec_chain(op, buf, orig, prev, prog.chunks,
+                                  prog.nranks, rank, axis, use_pallas)
             i += 1
         elif isinstance(op, StackedRecv):
-            buf = _exec_stacked(op, buf, orig, prog.chunks, rank, axis)
+            with jax.named_scope("uop.stacked"):
+                buf = _exec_stacked(op, buf, orig, prog.chunks, rank, axis)
             i += 1
         elif isinstance(op, Copy) and op.kind == "bruck_post":
-            buf = _chunk_roll(buf, prog.chunks, rank + 1, reverse=True)
+            with jax.named_scope("uop.rotate"):
+                buf = _chunk_roll(buf, prog.chunks, rank + 1, reverse=True)
             i += 1
         elif isinstance(op, SegLoop) or (
                 isinstance(op, Copy) and op.kind == "load"):
@@ -636,10 +652,12 @@ def execute_program(prog: Program, buf, axis, *,
                 body, k_req = ops[i:j + 1], 1
                 i = j + 1
             step = body[0].step
-            off, mask_idxs, new_val, raw = _exchange_update(
-                body, k_req, buf, orig, prev, prog.chunks, rank, step,
-                axis, use_pallas)
-            buf = _apply_write(buf, prog.chunks, off, mask_idxs, new_val)
+            with jax.named_scope("uop.exchange"):
+                off, mask_idxs, new_val, raw = _exchange_update(
+                    body, k_req, buf, orig, prev, prog.chunks, rank, step,
+                    axis, use_pallas)
+                buf = _apply_write(buf, prog.chunks, off, mask_idxs,
+                                   new_val)
             if raw is not None:
                 prev = raw
         else:
@@ -841,7 +859,10 @@ class CollectiveEngine:
             self.metrics.inc("sched_cache_hits")
             return sched
         self.metrics.inc("gen_calls")
-        sched = _gen_schedule(collective, algorithm, comm, root, op)
+        with telemetry.current().span("schedule", track="engine",
+                                      collective=collective,
+                                      algorithm=algorithm):
+            sched = _gen_schedule(collective, algorithm, comm, root, op)
         self._sched_cache[key] = sched
         return sched
 
@@ -888,12 +909,15 @@ class CollectiveEngine:
 
     def _execute(self, sched: Schedule, buf, axis,
                  compression: Optional[str] = None):
-        """Compile (memoized) and run through the one data plane."""
+        """Compile (memoized) and run through the one data plane, under
+        the device scope `algo.<schedule name>`."""
         prog = sched.compile(codec=compression, verify=self.verify)
         if isinstance(axis, tuple):
             outer_ax, inner_ax = axis
             axis = {"inter": outer_ax, "intra": inner_ax}
-        return execute_program(prog, buf, axis, use_pallas=self.use_pallas)
+        with jax.named_scope("algo." + sched.name):
+            return execute_program(prog, buf, axis,
+                                   use_pallas=self.use_pallas)
 
     def run(self, fn, in_specs, out_specs):
         """shard_map wrapper for standalone (F2F-style) engine programs."""
@@ -1013,6 +1037,7 @@ class CollectiveEngine:
         return out[:size].reshape(shape)
 
     # -- MPI-like API (paper Listing 1) --------------------------------------
+    @telemetry.named_scope("engine.allreduce")
     def allreduce(self, x, axis, op: str = "add",
                   algorithm: str = "auto",
                   compression: Optional[str] = None,
@@ -1025,12 +1050,13 @@ class CollectiveEngine:
         if n == 1:
             return x
         if self.backend == "native" and algorithm in (None, "auto"):
-            if op == "add":
-                return lax.psum(x, axis)
-            if op == "max":
-                return lax.pmax(x, axis)
-            if op == "min":
-                return lax.pmin(x, axis)
+            with jax.named_scope("algo.native"):
+                if op == "add":
+                    return lax.psum(x, axis)
+                if op == "max":
+                    return lax.pmax(x, axis)
+                if op == "min":
+                    return lax.pmin(x, axis)
         sched = self._resolve("allreduce", x, axis, algorithm, op=op,
                               segments=segments, compression=compression)
         # Padding stays a function of chunks alone so the chunk layout —
@@ -1042,6 +1068,7 @@ class CollectiveEngine:
         out = self._execute(sched, flat, axis, compression)
         return out[:size].reshape(shape)
 
+    @telemetry.named_scope("engine.reduce_scatter")
     def reduce_scatter(self, x, axis, op: str = "add",
                        algorithm: str = "auto",
                        compression: Optional[str] = None,
@@ -1058,9 +1085,10 @@ class CollectiveEngine:
         if x.size % n:
             raise ValueError(f"reduce_scatter size {x.size} % {n} != 0")
         if self.backend == "native" and algorithm in (None, "auto"):
-            return lax.psum_scatter(x.reshape(n, -1), axis,
-                                    scatter_dimension=0,
-                                    tiled=False).reshape(-1)
+            with jax.named_scope("algo.native"):
+                return lax.psum_scatter(x.reshape(n, -1), axis,
+                                        scatter_dimension=0,
+                                        tiled=False).reshape(-1)
         sched = self._resolve("reduce_scatter", x, axis, algorithm, op=op,
                               segments=segments, compression=compression)
         flat = x.reshape(-1)
@@ -1070,6 +1098,7 @@ class CollectiveEngine:
         own = sched.owned_chunk(rank)
         return lax.dynamic_slice_in_dim(out, own * csize, csize, 0)
 
+    @telemetry.named_scope("engine.allgather")
     def allgather(self, x, axis, algorithm: str = "auto",
                   segments: Optional[int] = None):
         """Tiled: returns concat of every rank's flat x (own shard at
@@ -1082,8 +1111,9 @@ class CollectiveEngine:
         if n == 1:
             return x.reshape(-1)
         if self.backend == "native" and algorithm in (None, "auto"):
-            return lax.all_gather(x.reshape(-1), axis, axis=0,
-                                  tiled=True)
+            with jax.named_scope("algo.native"):
+                return lax.all_gather(x.reshape(-1), axis, axis=0,
+                                      tiled=True)
         sched = self._resolve("allgather", x, axis, algorithm,
                               segments=segments)
         flat = x.reshape(-1)
@@ -1093,6 +1123,7 @@ class CollectiveEngine:
             buf, flat, rank * flat.shape[0], 0)
         return self._execute(sched, buf, axis)
 
+    @telemetry.named_scope("engine.bcast")
     def bcast(self, x, axis, root: int = 0, algorithm: str = "auto",
               segments: Optional[int] = None):
         if isinstance(axis, tuple):
@@ -1103,14 +1134,15 @@ class CollectiveEngine:
         if n == 1:
             return x
         if self.backend == "native" and algorithm in (None, "auto"):
-            full = lax.all_gather(x, axis)
-            return full[root]
+            with jax.named_scope("algo.native"):
+                return lax.all_gather(x, axis)[root]
         sched = self._resolve("bcast", x, axis, algorithm, root=root,
                               segments=segments)
         flat, shape, size = _flatten_pad(x, sched.chunks)
         out = self._execute(sched, flat, axis)
         return out[:size].reshape(shape)
 
+    @telemetry.named_scope("engine.reduce")
     def reduce(self, x, axis: str, root: int = 0, op: str = "add",
                algorithm: str = "auto", segments: Optional[int] = None):
         """MPI semantics: result meaningful at `root` only (other ranks may
@@ -1119,20 +1151,24 @@ class CollectiveEngine:
         if n == 1:
             return x
         if self.backend == "native" and algorithm in (None, "auto"):
-            return lax.psum(x, axis)
+            with jax.named_scope("algo.native"):
+                return lax.psum(x, axis)
         sched = self._resolve("reduce", x, axis, algorithm, root=root,
                               op=op, segments=segments)
         flat, shape, size = _flatten_pad(x, sched.chunks)
         out = self._execute(sched, flat, axis)
         return out[:size].reshape(shape)
 
+    @telemetry.named_scope("engine.gather")
     def gather(self, x, axis: str, root: int = 0, algorithm: str = "auto"):
         """Root ends with concat of all ranks' flat x (others undefined)."""
         n = self.mesh.shape[axis]
         if n == 1:
             return x.reshape(-1)
         if self.backend == "native" and algorithm in (None, "auto"):
-            return lax.all_gather(x.reshape(-1), axis, axis=0, tiled=True)
+            with jax.named_scope("algo.native"):
+                return lax.all_gather(x.reshape(-1), axis, axis=0,
+                                      tiled=True)
         sched = self._resolve("gather", x, axis, algorithm, root=root)
         flat = x.reshape(-1)
         rank = lax.axis_index(axis)
@@ -1146,6 +1182,7 @@ class CollectiveEngine:
             out = jnp.roll(grp, root, axis=0).reshape(-1)
         return out
 
+    @telemetry.named_scope("engine.alltoall")
     def alltoall(self, x, axis: str, algorithm: str = "auto",
                  segments: Optional[int] = None):
         """Tiled on leading dim: block j of the output came from rank j."""
@@ -1155,12 +1192,14 @@ class CollectiveEngine:
         if x.shape[0] % n:
             raise ValueError(f"alltoall dim0 {x.shape[0]} % {n} != 0")
         if self.backend == "native" and algorithm in (None, "auto"):
-            return lax.all_to_all(x, axis, split_axis=0, concat_axis=0,
-                                  tiled=True)
+            with jax.named_scope("algo.native"):
+                return lax.all_to_all(x, axis, split_axis=0, concat_axis=0,
+                                      tiled=True)
         sched = self._resolve("alltoall", x, axis, algorithm,
                               segments=segments)
         return self._execute(sched, x, axis)
 
+    @telemetry.named_scope("engine.collective")
     def collective(self, name: str, x, axis: str, *,
                    algorithm: str = "auto", root: int = 0, op: str = "add",
                    compression: Optional[str] = None,
@@ -1194,11 +1233,13 @@ class CollectiveEngine:
             return lax.dynamic_slice_in_dim(out, own * csize, csize, 0)
         return out[:size].reshape(shape)
 
+    @telemetry.named_scope("engine.send_recv", "algo.ring")
     def send_recv(self, x, axis: str, shift: int = 1):
         """Neighbour exchange along a ring (the paper's send/recv pair)."""
         comm = self.comm(axis)
         return lax.ppermute(x, axis, comm.ring_perm(shift))
 
+    @telemetry.named_scope("engine.barrier")
     def barrier(self, axis: str):
         """1-element allreduce, like the paper's barrier collective."""
         return self.allreduce(jnp.zeros((1,), jnp.float32), axis,
@@ -1289,6 +1330,7 @@ class CollectiveEngine:
                           timeout=timeout, **kwargs)
 
     # -- hierarchical multi-axis collectives (multi-pod path) ----------------
+    @telemetry.named_scope("engine.allreduce_multi")
     def allreduce_multi(self, x, axes: Sequence[str], op: str = "add",
                         algorithm: str = "auto",
                         compression: Optional[str] = None):
@@ -1334,6 +1376,7 @@ class CollectiveEngine:
         return jnp.dot(a, b,
                        preferred_element_type=jnp.float32).astype(out_dtype)
 
+    @telemetry.named_scope("engine.allgather_matmul", "algo.ring")
     def allgather_matmul(self, x, w, axis: str, segments: int = 1):
         """y = allgather(x, rows) @ w without staging the gathered buffer.
 
@@ -1370,6 +1413,7 @@ class CollectiveEngine:
                                int(x.size * x.dtype.itemsize)))
         return out
 
+    @telemetry.named_scope("engine.matmul_reduce_scatter", "algo.ring")
     def matmul_reduce_scatter(self, x, w, axis: str, segments: int = 1):
         """Row-sharded output of (x @ w) with the partial-sum reduction
         streamed around the ring. x: (m, k_local); w: (k_local, p);
@@ -1402,6 +1446,7 @@ class CollectiveEngine:
                                int(partial.size * partial.dtype.itemsize)))
         return accs[0] if segs == 1 else jnp.concatenate(accs, axis=0)
 
+    @telemetry.named_scope("engine.ring_attention", "algo.ring")
     def ring_attention(self, q, k, v, axis: str, *, causal: bool = True,
                        scale: Optional[float] = None, segments: int = 1):
         """Context-parallel attention: the streaming API generalized.
@@ -1492,6 +1537,7 @@ class CollectiveEngine:
     #: ring pipeline without monopolizing HBM for the fused buffer.
     BUCKET_BYTES = 4 << 20
 
+    @telemetry.named_scope("engine.tree_allreduce")
     def tree_allreduce(self, tree, axes: Sequence[str], op: str = "add",
                        compression: Optional[str] = None,
                        algorithm: str = "auto",

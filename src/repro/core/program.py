@@ -979,7 +979,9 @@ def _ensure_verified(prog: Program, schedule: Schedule, mode: str,
     if mode in done or "full" in done:
         return
     from repro.core import verify as _verify
-    _verify.verify_program(prog, schedule, level=mode)
+    with telemetry.current().span("verify", track="compile",
+                                  schedule=schedule.name, verify=mode):
+        _verify.verify_program(prog, schedule, level=mode)
     done.add(mode)
 
 

@@ -366,22 +366,17 @@ class Selector:
                            collective=collective, msg_bytes=int(msg_bytes),
                            algorithm=hit.algorithm, protocol=hit.protocol)
             return hit
-        if tr.enabled:
-            with tr.span("selector.choose", track="selector",
-                         collective=collective, nranks=comm.size,
-                         msg_bytes=int(msg_bytes), codec=codec) as sp:
-                choice = self._choose_uncached(
-                    collective, msg_bytes, comm, codec, elem_bytes,
-                    lead_dim, eager_cap=eager_cap)
-                sp.add(algorithm=choice.algorithm, protocol=choice.protocol,
-                       segments=choice.segments,
-                       predicted_s=choice.predicted_s,
-                       candidates_priced=self._last_priced,
-                       margin_s=self._last_margin)
-        else:
-            choice = self._choose_uncached(collective, msg_bytes, comm,
-                                           codec, elem_bytes, lead_dim,
-                                           eager_cap=eager_cap)
+        with tr.span("selector.choose", track="selector",
+                     collective=collective, nranks=comm.size,
+                     msg_bytes=int(msg_bytes), codec=codec) as sp:
+            choice = self._choose_uncached(
+                collective, msg_bytes, comm, codec, elem_bytes,
+                lead_dim, eager_cap=eager_cap)
+            sp.add(algorithm=choice.algorithm, protocol=choice.protocol,
+                   segments=choice.segments,
+                   predicted_s=choice.predicted_s,
+                   candidates_priced=self._last_priced,
+                   margin_s=self._last_margin)
         self._cache[key] = choice
         return choice
 

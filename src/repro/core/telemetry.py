@@ -1,18 +1,19 @@
-"""Unified telemetry: the virtual-clock tracer and the metrics registry.
+"""Unified telemetry: the control-plane tracer and the metrics registry.
 
 This repo prices everything it executes — `Program.cost_terms`,
-`Sequencer.makespan`, `MeshMakespan` over `FabricOccupancy` — but until
-this module it surfaced almost none of it: control-plane counters lived
-in four ad-hoc dicts and the priced per-link/per-request schedule was
-collapsed to one scalar. Two primitives fix that:
+`Sequencer.makespan`, `MeshMakespan` over `FabricOccupancy` — and does
+its own control-plane work at trace time: schedule generation, the
+selector's pricing, micro-op compile and verification. Two primitives
+surface both:
 
 :class:`Tracer`
-    Spans + instant events + typed counters on TWO clocks:
+    Spans + instant events on TWO clocks:
 
-    * the **control-plane tick clock** — a deterministic monotone
-      counter stamping trace-time work (selector choices, compiles,
-      engine drains).  No wall clock is ever consulted, so traces are
-      bit-reproducible;
+    * the **wall clock** (`time.perf_counter_ns`) for control-plane
+      work (selector choices, schedule generation, compiles, verifies,
+      engine drains).  Every span is also a profiler TraceMe of the
+      same name (`jax.profiler.TraceAnnotation`), so a device trace
+      captured around the work holds the span on the device's clock;
     * the **virtual clock** — priced seconds.  `interval()` records
       per-request and per-link occupancy windows (`simulate_drain`,
       `MeshMakespan.timeline()`), the same numbers the makespan model
@@ -32,9 +33,15 @@ collapsed to one scalar. Two primitives fix that:
     `scripts/lint_conventions.py` flags new direct `.stats[...] =`
     writes).
 
-Zero overhead when off: the process-default tracer is :data:`NULL`,
-whose methods are no-ops and whose `span()` returns a shared null
-context manager.  Instrumented code guards argument assembly with
+Spans run only at trace time (they wrap Python control-plane work,
+never a compiled program), so they are on whether or not a `Tracer` is
+installed: the process-default tracer :data:`NULL` records no event,
+but its spans still open the profiler annotation, and the outermost
+engine span of each kind in :data:`ENGINE_SPANS` reports its wall
+seconds as the `jax.monitoring` duration event
+``/repro/engine/<kind>_duration`` — the channel JAX reports its own
+compile time on.  Instrumentation guards argument assembly for events
+only a recording tracer keeps (instants, intervals) with
 `tracer.enabled`.  **Pricing never reads the tracer** — enabling
 tracing cannot change a priced or executed bit (regression-gated by
 tests/test_telemetry.py and the bench baseline).
@@ -46,19 +53,45 @@ Scoping::
         ...  # everything issued/priced/drained here is recorded
     trace = tr.to_chrome_trace()
 
-This module is stdlib-only and imports nothing from `repro` — every
-core module may import it without cycles.
+This module imports JAX's profiler and monitoring, and nothing from
+`repro` — every core module may import it without cycles.
 """
 from __future__ import annotations
 
 import contextlib
+import functools
+import threading
+import time
 from collections.abc import Mapping
 from typing import Iterator, Optional
 
+import jax
+from jax import monitoring
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Tracer", "NullTracer", "MetricsRegistry", "StatsView",
-    "NULL", "current", "use", "axis_label",
+    "NULL", "current", "use", "axis_label", "ENGINE_SPANS", "named_scope",
 ]
+
+
+def named_scope(*names: str):
+    """Decorator: trace each call under the device scopes `names`
+    (`jax.named_scope`, outermost first).  The scopes are HLO metadata
+    (`op_name`), free at run time, and name the call's ops in a device
+    trace.  A fresh scope is entered per call: `jax.named_scope`'s own
+    decorator form keeps one context object for every call, so a call
+    that re-enters the same function leaves its scope on the name stack
+    of whatever the caller traces next."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            with contextlib.ExitStack() as stack:
+                for name in names:
+                    stack.enter_context(jax.named_scope(name))
+                return fn(*args, **kwargs)
+        return call
+    return wrap
 
 
 def axis_label(axis) -> str:
@@ -72,48 +105,93 @@ def axis_label(axis) -> str:
 # Tracer
 # ---------------------------------------------------------------------------
 
-#: pid of the control-plane track group (tick clock: 1 tick == 1 "us").
+#: pid of the control-plane track group (wall clock, exported as us).
 CONTROL_PID = 1
 #: pid of the virtual-clock track group (priced seconds, exported as us).
 VIRTUAL_PID = 2
 
+#: Engine control-plane span name -> the kind its outermost occurrence
+#: reports as ``/repro/engine/<kind>_duration`` (wall seconds).
+ENGINE_SPANS = {
+    "schedule": "schedule",
+    "selector.choose": "choose",
+    "compile": "compile",
+    "verify": "verify",
+}
+MONITOR_PREFIX = "/repro/engine/"
 
-class _NullSpan:
-    """Shared no-op span: entering, exiting, and annotating cost nothing."""
+# Open engine spans on this thread: only the outermost one reports, so
+# a compile inside `selector.choose` is not counted twice.
+_engine_depth = threading.local()
 
-    __slots__ = ()
 
-    def __enter__(self) -> "_NullSpan":
-        return self
+class _Span:
+    """Context manager for one control-plane span: a profiler TraceMe,
+    its wall duration, the monitoring event of an outermost engine span,
+    and (under a recording tracer) one "X" event."""
 
-    def __exit__(self, *exc) -> bool:
-        return False
+    __slots__ = ("_tracer", "name", "track", "args", "_start", "_annot",
+                 "_outermost")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str, track: str,
+                 args: dict):
+        self._tracer = tracer
+        self.name = name
+        self.track = track
+        self.args = args
+        self._start = 0
+        self._annot = None
+        self._outermost = False
 
     def add(self, **args) -> None:
-        pass
+        """Attach more args to the span (e.g. the outcome, post-hoc)."""
+        self.args.update(args)
 
+    def __enter__(self) -> "_Span":
+        self._annot = TraceAnnotation(self.name)
+        self._annot.__enter__()
+        if self.name in ENGINE_SPANS:
+            depth = getattr(_engine_depth, "n", 0)
+            self._outermost = depth == 0
+            _engine_depth.n = depth + 1
+        self._start = time.perf_counter_ns()
+        return self
 
-_NULL_SPAN = _NullSpan()
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        end = time.perf_counter_ns()
+        self._annot.__exit__(exc_type, exc, tb)
+        if self.name in ENGINE_SPANS:
+            _engine_depth.n -= 1
+            if self._outermost:
+                monitoring.record_event_duration_secs(
+                    f"{MONITOR_PREFIX}{ENGINE_SPANS[self.name]}_duration",
+                    (end - self._start) * 1e-9)
+        if self._tracer is not None:
+            if exc_type is not None:
+                self.args.setdefault("error", exc_type.__name__)
+            self._tracer._events.append({
+                "type": "span", "name": self.name, "track": self.track,
+                "pid": CONTROL_PID, "ts": self._tracer._us(self._start),
+                "dur": (end - self._start) * 1e-3, "args": self.args,
+            })
+        return False
 
 
 class NullTracer:
-    """The process-default tracer: every method is a no-op.
+    """The process-default tracer: records no event.
 
     `enabled` is False so instrumentation can skip argument assembly
-    entirely; calling the methods anyway is still safe and free of
-    side effects.
+    for instants and intervals; its spans still annotate the profiler
+    and report engine monitoring events (see the module docstring).
     """
 
     enabled = False
 
-    def span(self, name: str, track: str = "control", **args) -> _NullSpan:
-        return _NULL_SPAN
+    def span(self, name: str, track: str = "control", **args) -> _Span:
+        return _Span(None, name, track, args)
 
     def instant(self, name: str, track: str = "control",
                 ts_s: Optional[float] = None, **args) -> None:
-        pass
-
-    def counter(self, name: str, value, track: str = "control") -> None:
         pass
 
     def interval(self, name: str, track: str, start_s: float, end_s: float,
@@ -128,89 +206,47 @@ class NullTracer:
 NULL = NullTracer()
 
 
-class _Span:
-    """Context manager recording one control-plane span ("X" event)."""
-
-    __slots__ = ("_tracer", "name", "track", "args", "_start")
-
-    def __init__(self, tracer: "Tracer", name: str, track: str, args: dict):
-        self._tracer = tracer
-        self.name = name
-        self.track = track
-        self.args = args
-        self._start = 0
-
-    def add(self, **args) -> None:
-        """Attach more args to the span (e.g. the outcome, post-hoc)."""
-        self.args.update(args)
-
-    def __enter__(self) -> "_Span":
-        self._start = self._tracer._next_tick()
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> bool:
-        end = self._tracer._next_tick()
-        if exc_type is not None:
-            self.args.setdefault("error", exc_type.__name__)
-        self._tracer._events.append({
-            "type": "span", "name": self.name, "track": self.track,
-            "pid": CONTROL_PID, "ts": self._start,
-            "dur": end - self._start, "args": self.args,
-        })
-        return False
-
-
 class Tracer:
-    """Recording tracer: spans, instants, counters, virtual intervals.
-
-    All timestamps are deterministic — the control-plane tick counter
-    and the priced virtual clock — so two identical runs produce
-    identical traces.  See the module docstring for the event model.
-    """
+    """Recording tracer: spans and instants on the wall clock, virtual
+    intervals on the priced clock.  See the module docstring for the
+    event model."""
 
     enabled = True
 
     def __init__(self):
         self._events: list = []
-        self._tick = 0
+        self._t0 = time.perf_counter_ns()
         # (pid, track) -> tid, assigned in first-use order
         self._tids: dict = {}
         self._installed_prev = []  # `with tracer:` scoping stack
 
-    def _next_tick(self) -> int:
-        self._tick += 1
-        return self._tick
+    def _us(self, ns: int) -> float:
+        """Wall microseconds since this tracer was made."""
+        return (ns - self._t0) * 1e-3
 
     # -- recording ----------------------------------------------------------
     def span(self, name: str, track: str = "control", **args) -> _Span:
         """Open a control-plane span; use as a context manager.  The
         returned span's `add(**args)` attaches outcome fields before it
         closes.  Spans on one track are well-nested by construction
-        (context-manager discipline + a global monotone tick clock)."""
+        (context-manager discipline on one monotone clock)."""
         return _Span(self, name, track, dict(args))
 
     def instant(self, name: str, track: str = "control",
                 ts_s: Optional[float] = None, **args) -> None:
-        """A marker: tick-clocked by default, or pinned to the virtual
-        clock when `ts_s` (priced seconds) is given."""
+        """A marker: on the wall clock by default, or pinned to the
+        virtual clock when `ts_s` (priced seconds) is given."""
         if ts_s is None:
             self._events.append({
                 "type": "instant", "name": name, "track": track,
-                "pid": CONTROL_PID, "ts": self._next_tick(), "args": args,
+                "pid": CONTROL_PID,
+                "ts": self._us(time.perf_counter_ns()), "args": args,
             })
         else:
             self._events.append({
                 "type": "instant", "name": name, "track": track,
                 "pid": VIRTUAL_PID, "ts": float(ts_s), "args": args,
             })
-
-    def counter(self, name: str, value, track: str = "control") -> None:
-        """A typed counter sample (Chrome "C" event)."""
-        self._events.append({
-            "type": "counter", "name": name, "track": track,
-            "pid": CONTROL_PID, "ts": self._next_tick(),
-            "args": {name: value},
-        })
 
     def interval(self, name: str, track: str, start_s: float, end_s: float,
                  **args) -> None:
@@ -263,38 +299,33 @@ class Tracer:
     def to_chrome_trace(self) -> dict:
         """Chrome trace-event JSON (the `{"traceEvents": [...]}` form).
 
-        Control-plane events live under pid 1 (1 tick == 1 us), virtual-
-        clock events under pid 2 (1 priced second == 1e6 us).  Each
-        track is a named thread; events are sorted by (pid, tid, ts) so
-        per-track timestamps are monotone.  Load the file in Perfetto
-        (ui.perfetto.dev) or chrome://tracing, or summarize it with
-        `scripts/trace_report.py`.
+        Control-plane events live under pid 1 (wall microseconds since
+        the tracer was made), virtual-clock events under pid 2 (1 priced
+        second == 1e6 us).  Each track is a named thread; events are
+        sorted by (pid, tid, ts) so per-track timestamps are monotone.
+        Load the file in Perfetto (ui.perfetto.dev) or chrome://tracing,
+        or summarize it with `scripts/trace_report.py`.
         """
         events = []
         for ev in self._events:
             pid = ev["pid"]
             tid = self._tid(pid, ev["track"])
-            ts = float(ev["ts"]) if pid == CONTROL_PID \
-                else float(ev["ts"]) * 1e6
+            scale = 1.0 if pid == CONTROL_PID else 1e6
+            ts = float(ev["ts"]) * scale
             if ev["type"] in ("span", "interval"):
-                dur = float(ev["dur"]) if pid == CONTROL_PID \
-                    else float(ev["dur"]) * 1e6
                 events.append({"ph": "X", "name": ev["name"], "cat": "repro",
-                               "pid": pid, "tid": tid, "ts": ts, "dur": dur,
+                               "pid": pid, "tid": tid, "ts": ts,
+                               "dur": float(ev["dur"]) * scale,
                                "args": ev["args"]})
-            elif ev["type"] == "instant":
+            else:  # instant
                 events.append({"ph": "i", "name": ev["name"], "cat": "repro",
                                "pid": pid, "tid": tid, "ts": ts, "s": "t",
-                               "args": ev["args"]})
-            else:  # counter
-                events.append({"ph": "C", "name": ev["name"], "cat": "repro",
-                               "pid": pid, "tid": tid, "ts": ts,
                                "args": ev["args"]})
         events.sort(key=lambda e: (e["pid"], e["tid"], e["ts"],
                                    -e.get("dur", 0.0)))
         meta = [
             {"ph": "M", "name": "process_name", "pid": CONTROL_PID, "tid": 0,
-             "args": {"name": "control-plane (ticks)"}},
+             "args": {"name": "control-plane (wall clock)"}},
             {"ph": "M", "name": "process_name", "pid": VIRTUAL_PID, "tid": 0,
              "args": {"name": "virtual-clock (priced seconds)"}},
         ]
@@ -306,8 +337,9 @@ class Tracer:
 
     def snapshot(self) -> dict:
         """Flat summary of the event stream: per-name span/interval
-        counts and total durations, instant counts, last counter
-        values, and the total event count."""
+        counts and total durations (spans in wall microseconds,
+        intervals in priced seconds), instant counts, and the total
+        event count."""
         out: dict = {"events": len(self._events)}
         for ev in self._events:
             if ev["type"] in ("span", "interval"):
@@ -315,11 +347,9 @@ class Tracer:
                 out[k] = out.get(k, 0) + 1
                 kd = f"{ev['type']}.{ev['name']}.total"
                 out[kd] = out.get(kd, 0.0) + float(ev["dur"])
-            elif ev["type"] == "instant":
+            else:
                 k = f"instant.{ev['name']}.count"
                 out[k] = out.get(k, 0) + 1
-            else:
-                out[f"counter.{ev['name']}"] = ev["args"][ev["name"]]
         return out
 
 

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.dlrm import DLRMConfig
+from repro.core import telemetry
 from repro.models.common import Builder, sharded_initializer
 from repro.parallel.ops import ParCtx
 
@@ -83,13 +84,15 @@ def dlrm_init(cfg: DLRMConfig, mesh, seed: int = 0):
     return dlrm_initializer(cfg, mesh)(jax.random.PRNGKey(seed))
 
 
+@telemetry.named_scope("dlrm.lookup")
 def embedding_lookup(tables, indices, ctx: ParCtx, use_pallas: bool = False):
     """tables: (T, rows_local, dim) local slice over 'model'; indices:
     (B, T) global row ids. Returns (B, T*dim) concat vector, replicated.
 
     Each rank serves the rows it owns (partial vectors), then one engine
     allreduce assembles the concat vector — the paper's partial-embedding
-    transmission from memory nodes to compute nodes.
+    transmission from memory nodes to compute nodes. Runs under the device
+    scope `dlrm.lookup` (the allreduce nests under it as `engine.*`).
     """
     t, rows_l, dim = tables.shape
     tp = ctx.tp
@@ -111,32 +114,37 @@ def embedding_lookup(tables, indices, ctx: ParCtx, use_pallas: bool = False):
 
 
 def dlrm_forward(params, indices, ctx: ParCtx, use_pallas: bool = False):
-    """indices: (B_local, T) -> (B_local, out_dim) click-through logits."""
+    """indices: (B_local, T) -> (B_local, out_dim) click-through logits.
+    FC layer i runs under the device scope `dlrm.fc<i>`."""
     vec = embedding_lookup(params["tables"], indices, ctx, use_pallas)
     tp = ctx.tp
     x = vec
     for i, fc in enumerate(params["fc"]):
         w, bias = fc["w"], fc["b"]
-        if i == 0 and tp > 1:
-            # checkerboard FC1: row-partitioned input slice x column slice
-            in_l = w.shape[0]
-            x_slice = jax.lax.dynamic_slice_in_dim(
-                x, ctx.tp_rank() * in_l, in_l, 1)
-            if ctx.pcfg.collective_matmul:
-                y = ctx.engine.matmul_reduce_scatter(x_slice, w, ctx.tp_axis)
-                y = ctx.engine.allgather(y, ctx.tp_axis).reshape(
-                    x.shape[0], -1)
+        with jax.named_scope(f"dlrm.fc{i}"):
+            if i == 0 and tp > 1:
+                # checkerboard FC1: row-partitioned input slice x column
+                # slice
+                in_l = w.shape[0]
+                x_slice = jax.lax.dynamic_slice_in_dim(
+                    x, ctx.tp_rank() * in_l, in_l, 1)
+                if ctx.pcfg.collective_matmul:
+                    y = ctx.engine.matmul_reduce_scatter(x_slice, w,
+                                                         ctx.tp_axis)
+                    y = ctx.engine.allgather(y, ctx.tp_axis).reshape(
+                        x.shape[0], -1)
+                else:
+                    y = jnp.einsum("bi,io->bo", x_slice, w)
+                    y = ctx.engine.allreduce(y, ctx.tp_axis)
             else:
-                y = jnp.einsum("bi,io->bo", x_slice, w)
-                y = ctx.engine.allreduce(y, ctx.tp_axis)
-        else:
-            y = jnp.einsum("bi,io->bo", x, w)
-            if tp > 1 and 0 < i < len(params["fc"]) - 1:
-                # column-parallel: out-dim sharded; gather for next layer
-                y = ctx.engine.allgather(
-                    y.T, ctx.tp_axis).reshape(-1, x.shape[0]).T
-        y = y + bias
-        x = jax.nn.relu(y) if i < len(params["fc"]) - 1 else y
+                y = jnp.einsum("bi,io->bo", x, w)
+                if tp > 1 and 0 < i < len(params["fc"]) - 1:
+                    # column-parallel: out-dim sharded; gather for next
+                    # layer
+                    y = ctx.engine.allgather(
+                        y.T, ctx.tp_axis).reshape(-1, x.shape[0]).T
+            y = y + bias
+            x = jax.nn.relu(y) if i < len(params["fc"]) - 1 else y
     return x
 
 

@@ -1,10 +1,15 @@
 """Unified telemetry (core/telemetry.py).
 
-Four contracts:
+Five contracts:
 
-  * tracer semantics — process-default no-op, `use()` scoping, spans /
-    instants / counters, and a Chrome trace export whose control-plane
-    spans are well-nested and whose per-track timestamps are monotone;
+  * tracer semantics — process-default tracer records nothing, `use()`
+    scoping, spans / instants, and a Chrome trace export whose
+    control-plane spans are well-nested and whose per-track timestamps
+    are monotone;
+  * control-plane spans on the wall clock: each carries its wall
+    duration, is a host event of a captured profile under its own name,
+    and the outermost engine span of a kind reports one
+    `/repro/engine/<kind>_duration` monitoring event;
   * the `MetricsRegistry` behind every legacy `.stats` view stays
     read-compatible (mapping equality with plain dicts, live reads);
   * `MeshMakespan.timeline()` reconstructs the composed makespan
@@ -14,13 +19,21 @@ Four contracts:
   * observability is read-only: enabling a tracer changes no priced or
     simulated bit (pricing never reads the tracer).
 """
+import contextlib
+import glob
 import importlib.util
 import json
 import pathlib
+import re
+import time
 import types
+
+import jax
+import jax.numpy as jnp
 
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from repro.core import (
     CollectiveEngine, FaultPlan, FaultyTransport, MeshMakespan, PricingEnv,
@@ -68,10 +81,9 @@ def _feeds(reqs, seed, n=8):
 def test_default_tracer_is_noop():
     tr = telemetry.current()
     assert tr is telemetry.NULL and not tr.enabled
-    with tr.span("x", a=1) as sp:   # all free no-ops, never raise
+    with tr.span("x", a=1) as sp:   # records nothing, never raises
         sp.add(b=2)
     tr.instant("x")
-    tr.counter("c", 1)
     tr.interval("i", "track", 0.0, 1.0)
     tr.ingest_timeline({"queues": [], "requests": [], "links": []})
 
@@ -95,14 +107,166 @@ def test_span_records_args_exceptions_and_snapshot():
         with tr.span("work", track="t"):
             raise RuntimeError("boom")
     tr.instant("mark", track="t", detail=1)
-    tr.counter("depth", 3, track="t")
     snap = tr.snapshot()
     assert snap["span.work.count"] == 2
     assert snap["instant.mark.count"] == 1
-    assert snap["counter.depth"] == 3
     failed = [e for e in tr._events
               if e["type"] == "span" and "error" in e["args"]]
     assert len(failed) == 1 and failed[0]["args"]["error"] == "RuntimeError"
+
+
+def test_spans_carry_wall_durations_in_order_and_nested():
+    tr = telemetry.Tracer()
+    with tr.span("outer", track="t"):
+        tr.instant("before", track="t")
+        with tr.span("inner", track="t"):
+            time.sleep(0.02)
+        tr.instant("after", track="t")
+    outer, inner = (next(e for e in tr._events if e["name"] == n)
+                    for n in ("outer", "inner"))
+    before, after = (next(e for e in tr._events if e["name"] == n)
+                     for n in ("before", "after"))
+    # wall microseconds: the sleep shows in both spans' durations
+    assert inner["dur"] >= 2e4 and outer["dur"] >= inner["dur"]
+    assert outer["ts"] <= before["ts"] <= inner["ts"]
+    assert inner["ts"] + inner["dur"] <= after["ts"]
+    assert after["ts"] <= outer["ts"] + outer["dur"]
+    snap = tr.snapshot()
+    assert snap["span.inner.total"] >= 2e4
+
+
+def test_spans_are_host_events_of_a_captured_profile(tmp_path, eng8,
+                                                     monkeypatch):
+    """With no tracer installed, the control plane's spans still land in
+    a profile captured around the work, under their own names."""
+    from repro.core import program as program_mod
+    monkeypatch.setattr(program_mod, "_COMPILE_CACHE", {})
+    monkeypatch.setattr(program_mod, "_VERIFIED", {})
+    assert telemetry.current() is telemetry.NULL
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        Selector().choose("allgather", 3 << 14, eng8.comm("x"))
+    finally:
+        jax.profiler.stop_trace()
+    path = max(glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                         recursive=True))
+    data = jax.profiler.ProfileData.from_file(path)
+    names = {e.name for plane in data.planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert {"selector.choose", "compile", "verify"} <= names
+
+
+def test_outermost_engine_spans_report_monitoring_events(eng8):
+    """One `/repro/engine/<kind>_duration` event per outermost engine
+    span: the compiles and verifies inside `selector.choose` are part of
+    its seconds, never counted again."""
+    from repro.core import program as program_mod
+    seen = []
+
+    def listen(event, duration, **_):
+        if event.startswith(telemetry.MONITOR_PREFIX):
+            seen.append((event, duration))
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        sel = Selector()
+        sel.choose("allreduce", 5 << 14, eng8.comm("x"))
+        choose = list(seen)
+        sel.choose("allreduce", 5 << 14, eng8.comm("x"))   # memoized
+        memo = seen[len(choose):]
+        sched = eng8._cached_schedule("reduce_scatter", "ring",
+                                      eng8.comm("x"), 0, "add")
+        program_mod._COMPILE_CACHE.pop((sched, 3, None, True, True), None)
+        program_mod._VERIFIED.pop((sched, 3, None, True, True), None)
+        del seen[:]
+        program_mod.compile_schedule(sched, segments=3)
+        compiled = list(seen)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert [e for e, _ in choose] == ["/repro/engine/choose_duration"]
+    assert choose[0][1] > 0.0
+    assert memo == []
+    assert [e for e, _ in compiled] == ["/repro/engine/compile_duration"]
+
+
+# --------------------------------------------------------------------------
+# Device scopes: HLO metadata naming each engine call and micro-op kind
+# --------------------------------------------------------------------------
+
+def _strip_metadata(text: str) -> str:
+    """HLO text without its metadata and source-location tables."""
+    lines = [ln for ln in text.splitlines() if not re.match(
+        r"^(\d+ |FileNames|FunctionNames|FileLocations|StackFrames)", ln)]
+    return re.sub(r", metadata=\{[^}]*\}", "", "\n".join(lines))
+
+
+def _scoped_program(mesh, collective, algorithm, segments, compression):
+    eng = CollectiveEngine(mesh)
+    kw = {"algorithm": algorithm, "segments": segments}
+    if compression:
+        kw["compression"] = compression
+
+    def body(v):
+        return getattr(eng, collective)(v[0], "x", **kw)[None]
+    return eng.run(body, in_specs=P("x"), out_specs=P("x"))
+
+
+@pytest.mark.parametrize("collective,algorithm,segments,codec,kind", [
+    ("allgather", "ring", 1, None, "loop"),
+    ("allgather", "ring", 4, None, "stream"),
+    ("allgather", "recursive_doubling", 4, None, "chain"),
+    ("allgather", "recursive_doubling", 1, None, "exchange"),
+    ("alltoall", "bruck", 1, None, "rotate"),
+    ("alltoall", "linear", 1, None, "stacked"),
+    ("allreduce", "bidi_ring", 1, "int8", "codec"),
+    ("allreduce", "ring", 4, None, "combine"),
+])
+def test_device_scopes_name_the_call_and_micro_op(
+        mesh8, monkeypatch, collective, algorithm, segments, codec, kind):
+    """Each engine call's ops carry `engine.<call>/algo.<algorithm>` and
+    the micro-op kind that runs them in their op_name; the scopes are
+    metadata, so the program and its results are those traced without
+    them, bit for bit."""
+    x = jnp.asarray(np.random.default_rng(1).integers(
+        -8, 8, (8, 4096)).astype(np.float32))
+    fn = _scoped_program(mesh8, collective, algorithm, segments, codec)
+    text = fn.lower(x).compile().as_text()
+    names = set("/".join(re.findall(r'op_name="([^"]*)"', text)).split("/"))
+    assert {f"engine.{collective}", f"algo.{algorithm}",
+            f"uop.{kind}"} <= names
+    out = np.asarray(fn(x))
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = _scoped_program(mesh8, collective, algorithm, segments, codec)
+    plain_text = plain.lower(x).compile().as_text()
+    assert not re.search(r'op_name="[^"]*(engine|algo|uop)\.', plain_text)
+    assert _strip_metadata(plain_text) == _strip_metadata(text)
+    np.testing.assert_array_equal(np.asarray(plain(x)), out)
+
+
+def test_native_calls_are_scoped_native(mesh8):
+    eng = CollectiveEngine(mesh8, backend="native")
+    fn = eng.run(lambda v: eng.allreduce(v, "x"), in_specs=P("x"),
+                 out_specs=P("x"))
+    text = fn.lower(jnp.ones((8, 128), jnp.float32)).compile().as_text()
+    assert "engine.allreduce/algo.native" in text
+
+
+def test_named_scope_is_reentrant():
+    """A function that re-enters itself leaves no scope behind on what
+    its caller traces next (`jax.named_scope`'s own decorator form
+    does)."""
+    @telemetry.named_scope("outer.rec")
+    def rec(x, n):
+        return rec(x, n - 1) + 1 if n else x * 2
+
+    def f(x):
+        return jnp.sin(rec(x, 2))
+    text = jax.jit(f).lower(1.0).compile().as_text()
+    sin = [ln for ln in text.splitlines() if "sine(" in ln]
+    assert sin and "outer.rec" not in sin[0]
+    assert "outer.rec" in text
 
 
 # --------------------------------------------------------------------------
@@ -115,10 +279,11 @@ def _validate_chrome_trace(doc):
     well-nested per track (virtual-clock intervals are occupancy
     windows, which legitimately overlap)."""
     assert isinstance(doc["traceEvents"], list)
+    assert all(e["ph"] != "C" for e in doc["traceEvents"])
     per_track = {}
     named = set()
     for ev in doc["traceEvents"]:
-        assert ev["ph"] in ("X", "i", "C", "M")
+        assert ev["ph"] in ("X", "i", "M")
         if ev["ph"] == "M":
             if ev["name"] == "thread_name":
                 named.add((ev["pid"], ev["tid"]))
