@@ -772,6 +772,10 @@ def _engine_metrics() -> telemetry.MetricsRegistry:
     reg = telemetry.MetricsRegistry()
     reg.counter("gen_calls")
     reg.counter("sched_cache_hits")
+    # auto picks priced under serialized reducing waves, and auto picks
+    # that came out segmented (k >= 2)
+    reg.counter("selector.serial_wave_choices")
+    reg.counter("selector.streamed_choices")
     return reg
 
 
@@ -890,6 +894,10 @@ class CollectiveEngine:
                 codec=compression, elem_bytes=x.dtype.itemsize,
                 lead_dim=lead)
             algorithm = choice.algorithm
+            if not comm.hw.reduce_waves_overlap:
+                self.metrics.inc("selector.serial_wave_choices")
+            if choice.segments >= 2:
+                self.metrics.inc("selector.streamed_choices")
             if segments is None:
                 segments = choice.segments
             if root == 0 and op == "add":

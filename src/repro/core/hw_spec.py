@@ -47,6 +47,12 @@ class HwSpec:
     dcn_axes: tuple = ("pod",)
     # Rendezvous handshake: one extra round trip before payload.
     rendezvous_rtt: float = 2e-6
+    # Whether the segment waves of a stream (STREAM, STREAM_CHAIN) in a
+    # program that reduces overlap. True prices the cross-step fill/drain
+    # credit; False charges every wave its alpha plus its segment's wire
+    # time, one wave after another (`Program.cost`). Copy-only programs
+    # keep the credit either way.
+    reduce_waves_overlap: bool = True
 
     # MXU native tile (for kernel block alignment checks).
     mxu_dim: int = 128
@@ -68,14 +74,23 @@ ACCL_CLUSTER = HwSpec(
 
 TPU_V5E = HwSpec()
 
+# The v5e as measured on a 2x2 host (`scripts/stream_sweep.py`, PERF.md):
+# there a stream in an allreduce or reduce-scatter runs its segment waves
+# one after another, so segmenting those rings buys no overlap and costs
+# every wave its own latency, while every streamed allgather and
+# all-to-all beat its unsegmented form. Every other constant is the
+# modelled TPU_V5E's.
+TPU_V5E_MEASURED = dataclasses.replace(
+    TPU_V5E, name="tpu-v5e-measured", reduce_waves_overlap=False)
+
 # The TPUs this repo describes, keyed by JAX's `device.device_kind`.
-BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
+BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E_MEASURED}
 
 
 def hw_for_devices(devices) -> HwSpec:
     """The HwSpec of the chips `devices` are: a TPU no HwSpec describes
     is an error, never a default. The CPU's virtual devices stand in for
-    the modelled TPU_V5E."""
+    the modelled TPU_V5E; a real v5e gets its measured spec."""
     d = next(iter(devices))
     if d.platform != "tpu":
         return TPU_V5E

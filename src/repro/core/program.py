@@ -69,6 +69,14 @@ The selector therefore stops auto-picking segmentation where execution
 cannot cash the overlap; the credit is earned exactly where a fusion pass
 proved the reorder safe. The schedule-walk `predict_time` is retired.
 
+The credit also needs a chip on which the waves overlap. Where the
+communicator's `HwSpec.reduce_waves_overlap` is False (the measured v5e)
+and the program reduces (allreduce, reduce-scatter, reduce), its streamed
+regions are priced as they execute there: every one of their trip x k
+waves pays alpha plus its segment's wire time, with no drain credit (the
+serialized form above). Copy-only programs (gathers, all-to-all) keep the
+credit: on the v5e their streamed forms beat their unsegmented ones.
+
 Per-segment scale reuse (codecs): block codecs (int8) quantize in fixed
 element blocks. `fit_segments` only admits segment counts whose per-
 segment flat length is a whole number of codec blocks, so every scale
@@ -293,6 +301,13 @@ class Program:
         return "\n".join(out)
 
     # ---- program-level pricing (the alpha-beta walk) ---------------------
+    @property
+    def reduces(self) -> bool:
+        """Whether any exchange combines its arrivals with an op other
+        than copy (allreduce, reduce-scatter, reduce)."""
+        return any(body[-1].op != "copy"
+                   for _m, _k, body, _r in self.exchange_terms())
+
     def exchange_terms(self):
         """Yield (multiplicity, segments, body, region) per wire exchange.
 
@@ -356,7 +371,9 @@ class Program:
             the serialized mult * k_eff * t = mult * (k_eff * alpha +
             wire / bw) — at k > 1 that is never cheaper than unsegmented,
             so the selector cannot be lured into segmentation the data
-            plane cannot cash.
+            plane cannot cash. A STREAM / STREAM_CHAIN region of a
+            program that `reduces` is priced this way too when
+            `comm.hw.reduce_waves_overlap` is False.
 
         The total divides by `overlap_factor` when slots ride independent
         links. Wire bytes come from each SEND's `bytes_frac`, scaled by
@@ -492,8 +509,12 @@ class Program:
         single-fabric walk. `links` splits the wire half by physical
         link key (see `_level_fabrics`); it is a PARALLEL accumulator —
         the total/lat/wire float-op sequence is untouched, so adding it
-        cannot perturb golden parity."""
+        cannot perturb golden parity. Without the drain credit
+        (`reduce_waves_overlap` off and a program that `reduces`) the
+        streamed exchanges take the serialized branch and no region
+        drains."""
         fabrics = self._level_fabrics(comm)
+        overlap = comm.hw.reduce_waves_overlap or not self.reduces
         total = 0.0
         lat = 0.0
         wir = 0.0
@@ -519,7 +540,7 @@ class Program:
             b = wire / (k_eff * bw)
             t = alpha + b
             crossings += mult * k_eff
-            if region is not None:
+            if region is not None and overlap:
                 total += mult * t
                 lat += mult * alpha
                 wir += mult * b

@@ -297,3 +297,82 @@ def test_streamed_and_segloop_costs_disagree_where_the_model_says():
 def test_compile_rejects_zero_segments():
     with pytest.raises(ValueError):
         compile_schedule(A.ring_reduce_scatter(COMM8), segments=0)
+
+
+# --------------------------------------------------------------------------
+# Serialized stream waves (the measured v5e)
+# --------------------------------------------------------------------------
+
+REDUCING_STREAMS = (
+    [("allreduce", "bidi_ring", k) for k in (2, 4, 8)]
+    + [("allreduce", "ring", k) for k in (2, 8)]
+    + [("reduce_scatter", "ring", k) for k in (2, 4, 8)]
+    + [("reduce_scatter", "recursive_halving", k) for k in (4, 8)]
+    + [("allreduce", "halving_doubling", 8)]
+)
+COPY_STREAMS = (
+    [("allgather", "ring", k) for k in (2, 8)]
+    + [("allgather", "recursive_doubling", 8)]
+    + [("alltoall", "linear", k) for k in (2, 8)]
+)
+
+
+def _streamed(coll, algo, k):
+    prog = _gen(coll, algo, Communicator(axis="x", size=4)).with_segments(
+        k).compile()
+    waves = sum(op.trip * op.segments if isinstance(op, Stream)
+                else len(op.bodies) * op.segments
+                for op in prog.ops if isinstance(op, (Stream, StreamChain)))
+    assert waves >= 2 * k
+    return prog, waves
+
+
+@pytest.mark.parametrize("coll,algo,k", REDUCING_STREAMS,
+                         ids=[f"{c}-{a}-k{k}" for c, a, k
+                              in REDUCING_STREAMS])
+def test_serial_waves_price_at_least_every_wave(coll, algo, k):
+    """Under serialized waves a reducing program's stream costs at least
+    its trip x k waves' alphas plus its busiest link's wire bytes over
+    `ici_link_bw` (each link direction of a bidi ring carries half), so
+    no stream is priced below the wire floor the modelled spec's drain
+    credit dips under (4 MiB ring reduce-scatter at k=8: 38 us against a
+    63 us floor)."""
+    from repro.core.hw_spec import TPU_V5E, TPU_V5E_MEASURED
+    msg = 4 << 20
+    prog, waves = _streamed(coll, algo, k)
+    assert prog.reduces
+    measured = Communicator(axis="x", size=4, hw=TPU_V5E_MEASURED)
+    busiest = (prog.fabric_wire_bytes(msg, measured)["ici"]
+               / prog.overlap_factor)
+    floor = waves * TPU_V5E.ici_hop_latency + busiest / TPU_V5E.ici_link_bw
+    assert prog.cost(msg, measured) >= floor * (1 - 1e-12)
+    modelled = prog.cost(msg, Communicator(axis="x", size=4, hw=TPU_V5E))
+    assert modelled < prog.cost(msg, measured)
+
+
+@pytest.mark.parametrize("coll,algo,k", COPY_STREAMS,
+                         ids=[f"{c}-{a}-k{k}" for c, a, k in COPY_STREAMS])
+def test_copy_streams_keep_the_credit_on_the_measured_spec(coll, algo, k):
+    """A copy-only program prices bitwise as on the modelled spec."""
+    from repro.core.hw_spec import TPU_V5E, TPU_V5E_MEASURED
+    prog, _waves = _streamed(coll, algo, k)
+    assert not prog.reduces
+    assert prog.cost(4 << 20, Communicator(axis="x", size=4,
+                                           hw=TPU_V5E_MEASURED)) == \
+        prog.cost(4 << 20, Communicator(axis="x", size=4, hw=TPU_V5E))
+
+
+def test_serial_waves_equal_the_unfused_program():
+    """With no drain credit a streamed region prices as its unfused
+    (stream=False) compile does: the executor's k waves each pay alpha."""
+    from repro.core.hw_spec import TPU_V5E_MEASURED
+    comm = Communicator(axis="x", size=4, hw=TPU_V5E_MEASURED)
+    sched = A.ring_reduce_scatter(comm)
+    fused = compile_schedule(sched, segments=8)
+    plain = compile_schedule(sched, segments=8, stream=False)
+    assert any(isinstance(op, Stream) for op in fused.ops)
+    assert fused.cost(4 << 20, comm) == plain.cost(4 << 20, comm)
+    lat, wire, links = fused.cost_terms(4 << 20, comm, per_link=True)
+    assert math.isclose(lat + wire, fused.cost(4 << 20, comm),
+                        rel_tol=1e-12)
+    assert links == {("ici", "x"): wire}

@@ -357,3 +357,83 @@ def test_gather_shard_clamp_uses_shard_grid():
     # wrong shard/chunks grid (3 elements) would collapse to (1, 3)
     assert sel.fit_candidate_segments(sched, 24 * 4, (1, 2, 4, 8)) == \
         (1, 2, 4, 8)
+
+
+# --------------------------------------------------------------------------
+# The measured v5e: a stream's waves run one after another
+# --------------------------------------------------------------------------
+
+FIG10 = ("allreduce", "reduce_scatter", "allgather", "bcast", "reduce",
+         "gather", "alltoall")
+
+
+def _engine4(hw):
+    from repro.core import CollectiveEngine
+    from repro.core.topology import make_mesh
+    return CollectiveEngine(make_mesh((4,), ("x",)), hw=hw)
+
+
+def _pick(eng, collective, size):
+    c = eng.selector.choose(collective, size, eng.comm("x"))
+    return c.algorithm, c.protocol, c.segments
+
+
+@pytest.mark.parametrize("size", [4096, 131072])
+@pytest.mark.parametrize("collective", FIG10)
+def test_measured_v5e_keeps_small_picks(collective, size):
+    """No small pick of the collective grid streams, and a stream's
+    price can only rise under serialized waves: the measured spec picks
+    exactly what the modelled one does at 4 KiB and 128 KiB."""
+    from repro.core.hw_spec import TPU_V5E, TPU_V5E_MEASURED
+    assert _pick(_engine4(TPU_V5E_MEASURED), collective, size) == \
+        _pick(_engine4(TPU_V5E), collective, size)
+
+
+@pytest.mark.parametrize("collective", ["allreduce", "reduce_scatter"])
+def test_measured_v5e_picks_no_stream_at_4mib(collective):
+    """At 4 MiB the modelled spec streams these in 8 segments; on the
+    measured chip each wave of a reducing stream costs its own alpha, so
+    one unsegmented wave per step wins."""
+    from repro.core.hw_spec import TPU_V5E, TPU_V5E_MEASURED
+    from repro.core.program import Stream, StreamChain
+    assert _pick(_engine4(TPU_V5E), collective, 4 << 20)[2] == 8
+    eng = _engine4(TPU_V5E_MEASURED)
+    c = eng.selector.choose(collective, 4 << 20, eng.comm("x"))
+    assert c.segments == 1, c
+    assert not any(isinstance(op, (Stream, StreamChain))
+                   for op in c.program.ops)
+
+
+@pytest.mark.parametrize("collective", ["allgather", "alltoall", "bcast",
+                                        "gather"])
+def test_measured_v5e_keeps_copy_picks_at_4mib(collective):
+    """Copy-only programs keep the drain credit on the measured chip
+    (their streamed forms beat their unsegmented ones there): the 4 MiB
+    picks, the streamed allgather and all-to-all among them, stay."""
+    from repro.core.hw_spec import TPU_V5E, TPU_V5E_MEASURED
+    assert _pick(_engine4(TPU_V5E_MEASURED), collective, 4 << 20) == \
+        _pick(_engine4(TPU_V5E), collective, 4 << 20)
+
+
+def test_serial_wave_counters_count():
+    """Each auto pick under serialized waves counts once; a pick that
+    comes out segmented counts in the streamed counter; an explicit
+    algorithm is no pick and counts in neither."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from repro.core.hw_spec import TPU_V5E, TPU_V5E_MEASURED
+
+    def traced(eng, **kw):
+        f = jax.shard_map(lambda v: eng.allreduce(v[0], "x", **kw)[None],
+                          mesh=eng.mesh, in_specs=P("x"), out_specs=P("x"),
+                          check_vma=False)
+        jax.eval_shape(f, jax.ShapeDtypeStruct((4, 1 << 20), jnp.float32))
+        return (eng.metrics.get("selector.serial_wave_choices"),
+                eng.metrics.get("selector.streamed_choices"))
+
+    measured = _engine4(TPU_V5E_MEASURED)
+    assert traced(measured) == (1, 0)
+    assert traced(measured) == (2, 0)
+    assert traced(measured, algorithm="bidi_ring", segments=8) == (2, 0)
+    assert traced(_engine4(TPU_V5E)) == (0, 1)

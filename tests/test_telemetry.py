@@ -397,7 +397,9 @@ def test_metrics_registry_counters_gauges_records():
 
 
 def test_component_stats_views_read_compatible(eng8):
-    assert eng8.stats == {"gen_calls": 0, "sched_cache_hits": 0}
+    assert eng8.stats == {"gen_calls": 0, "sched_cache_hits": 0,
+                          "selector.serial_wave_choices": 0,
+                          "selector.streamed_choices": 0}
     assert eng8.selector.stats == {"choose_calls": 0, "cache_hits": 0,
                                    "gen_calls": 0}
     seq = Sequencer(eng8)

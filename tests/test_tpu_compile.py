@@ -21,7 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from repro.configs.base import ParallelConfig
 from repro.configs.dlrm import CONFIG as DLRM_TABLE2
 from repro.core import CollectiveEngine
-from repro.core.hw_spec import TPU_V5E, hw_for_devices
+from repro.core.hw_spec import TPU_V5E, TPU_V5E_MEASURED, hw_for_devices
 from repro.kernels import fused_reduce, matmul, ops, quantize
 from repro.models import dlrm as dlrm_mod
 from repro.models.common import Builder
@@ -58,7 +58,7 @@ def _compile(fn, *shapes):
 
 
 def test_topology_is_v5e(topo):
-    assert hw_for_devices(topo.devices) is TPU_V5E
+    assert hw_for_devices(topo.devices) is TPU_V5E_MEASURED
 
 
 def test_quantize_dequantize_compile(one_chip):
@@ -148,8 +148,11 @@ def test_dlrm_one_chip_forward_fits(topo, monkeypatch, use_pallas):
     assert ("tpu_custom_call" in c.as_text()) == use_pallas
 
 
-@pytest.mark.parametrize("collective", ["allreduce", "alltoall"])
+@pytest.mark.parametrize("collective", ["allreduce", "alltoall",
+                                        "reduce_scatter", "allgather"])
 def test_microcode_collectives_compile_on_four_chips(topo, collective):
+    """The 4 MiB picks the engine makes on the chip (its measured spec:
+    unsegmented allreduce and reduce-scatter) compile for four chips."""
     mesh = _mesh(topo, (4,), ("x",))
     eng = CollectiveEngine(mesh, backend="microcode")
     fn = getattr(eng, collective)
