@@ -19,7 +19,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.configs.base import ParallelConfig
-from repro.configs.dlrm import CONFIG as DLRM_TABLE2
+from repro.configs.dlrm import CONFIG as DLRM_TABLE2, dlrm_v2
 from repro.core import CollectiveEngine
 from repro.core.hw_spec import TPU_V5E, TPU_V5E_MEASURED, hw_for_devices
 from repro.kernels import fused_reduce, matmul, ops, quantize
@@ -162,3 +162,38 @@ def test_microcode_collectives_compile_on_four_chips(topo, collective):
     x = jax.ShapeDtypeStruct((4, (4 << 20) // 4), jnp.float32,
                              sharding=NamedSharding(mesh, P("x")))
     assert "collective-permute" in g.lower(x).compile().as_text()
+
+
+def test_dlrm_dcnv2_step_compiles_on_four_chips(topo):
+    """DLRM-DCNv2 at the benchmark's cut (the five 40M-row tables at 18M:
+    12.06 GB of packed tables per chip) and batch 2048 on a 2x2, with the
+    engine priced as on the chip: the serving step compiles, holds the
+    tables and its temporaries in each chip's HBM, and hands the bags to
+    their owners through the engine's collective-permutes."""
+    v2 = dlrm_v2()
+    cfg = dataclasses.replace(v2, table_rows=tuple(
+        18_000_000 if r == 40_000_000 else r for r in v2.rows))
+    mesh = _mesh(topo, (1, 1, 4), ("pod", "data", "model"))
+    pshapes = dlrm_mod.dlrm_params(
+        Builder("shape", mesh=mesh, dtype=jnp.float32), cfg, 4)
+    specs = dlrm_mod.dlrm_specs(cfg, 4)
+    ctx = ParCtx(engine=CollectiveEngine(mesh, hw=TPU_V5E_MEASURED),
+                 pcfg=ParallelConfig(), mesh=mesh)
+    fwd = jax.jit(jax.shard_map(
+        lambda p, i, d: dlrm_mod.dlrm_forward(p, i, ctx, dense=d, cfg=cfg),
+        mesh=mesh, in_specs=(specs, P(None, None), P("model", None)),
+        out_specs=P("model", None), check_vma=False))
+    ids = jax.ShapeDtypeStruct((2048, cfg.ids_per_query), jnp.int32,
+                               sharding=NamedSharding(mesh, P()))
+    dense = jax.ShapeDtypeStruct(
+        (2048, cfg.dense_features), jnp.float32,
+        sharding=NamedSharding(mesh, P("model", None)))
+    with jax.default_matmul_precision("highest"):
+        c = fwd.lower(pshapes, ids, dense).compile()
+    mem = c.memory_analysis()
+    assert 12.0e9 < mem.argument_size_in_bytes < TPU_V5E.hbm_bytes, mem
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < TPU_V5E.hbm_bytes, mem
+    assert "collective-permute" in c.as_text()
+    assert ctx.engine.metrics.get("dlrm.bag_collective_bytes") == \
+        2048 * 26 * 128 * 4
