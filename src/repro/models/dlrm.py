@@ -27,10 +27,12 @@ tables are packed into one (tp * rows_local, dim) array sharded over
 'model' on rows, each table split row-wise into 128-aligned shards
 (`table_layout`), so every chip holds a 1/tp slice of every table. Each
 chip sum-pools, for every sample of the global batch, the ids of each bag
-that fall in its rows (`bag_partials`); one engine reduce-scatter over
-'model' sums the partial bags and leaves each chip the pooled bags of its
-B/tp samples; the bottom MLP, the low-rank cross network and the top MLP
-then run data-parallel on those samples and their dense features.
+that fall in its rows, compacted on the device to a capacity set by the
+shapes so that it gathers only those rows (`bag_partials`); one engine
+reduce-scatter over 'model' sums the partial bags and leaves each chip
+the pooled bags of its B/tp samples; the bottom MLP, the low-rank cross
+network and the top MLP then run data-parallel on those samples and their
+dense features.
 """
 from __future__ import annotations
 
@@ -49,6 +51,10 @@ from repro.parallel.ops import ParCtx
 # Rows per shard are a multiple of this, so the Pallas lookup reads every
 # shard in place (kernels/embedding_gather.py: V on the 128 lanes).
 ROW_ALIGN = 128
+
+# A row shard's compacted bags hold a multiple of this many ids
+# (`bag_capacity`).
+BAG_CAPACITY_ALIGN = 1024
 
 
 def table_layout(cfg: DLRMConfig, tp: int):
@@ -218,6 +224,27 @@ def dlrm_forward(params, indices, ctx: ParCtx, use_pallas: bool = False,
     return x
 
 
+def bag_capacity(cfg: DLRMConfig, batch: int, tp: int) -> int:
+    """Rows one row shard gathers for the bags of a batch of `batch`
+    queries (`bag_partials`): every id where tp = 1, where one shard holds
+    every table; else the shard's even share of the ids plus an eighth of
+    them for skew, rounded up to BAG_CAPACITY_ALIGN and at most every id.
+    Batch 2048 of MLPerf's bags on 4 shards: 164,864 of 438,272."""
+    ids = batch * cfg.ids_per_query
+    if tp == 1:
+        return ids
+    cap = -(-ids * (tp + 8) // (8 * tp))
+    return min(ids, -(-cap // BAG_CAPACITY_ALIGN) * BAG_CAPACITY_ALIGN)
+
+
+def _bag_sums(rows, hots):
+    """(B, ids_per_query, dim) rows, each table's bag side by side ->
+    (B, n_tables * dim) sums per bag."""
+    starts = itertools.accumulate((0,) + hots[:-1])
+    return jnp.concatenate([rows[:, s:s + m].sum(axis=1)
+                            for s, m in zip(starts, hots)], axis=1)
+
+
 def bag_partials(table, indices, cfg: DLRMConfig, tp: int, rank):
     """One row shard's part of every bag. table: the shard's
     (rows_local, dim) block of the packed tables (`table_layout`);
@@ -225,20 +252,52 @@ def bag_partials(table, indices, cfg: DLRMConfig, tp: int, rank):
     side in table order; rank: the shard (an int or traced). Returns
     (B, n_tables * dim): per sample, each table's sum of the rows of its
     bag that this shard holds. The tp shards' partials add up to the
-    pooled bags. Every id is gathered (ids the shard does not hold read
-    its row 0) and masked: a static shape, whatever the ids."""
+    pooled bags.
+
+    With tp = 1 every id is the shard's and is gathered. Otherwise one
+    sort moves the flat positions of the shard's ids, with their local
+    rows, to the front in order, and the first `bag_capacity` are kept
+    (left-over slots point past the batch and read row 0); only those
+    rows are gathered, and a sorted segment sum pools each into its bag,
+    `query * n_tables + table`. Where a batch puts more ids on the shard
+    than that, a `lax.cond` swaps in the masked bags, computed under the
+    device scope `dlrm.bag_overflow`: every id gathered (ids the shard
+    does not hold read its row 0) and masked. Either way the answer is
+    exact."""
     per, off, _ = table_layout(cfg, tp)
     hots = cfg.hots
-    slot_per = np.repeat(np.asarray(per, np.int32), hots)
+    b, n = indices.shape
+    t, dim = len(hots), table.shape[1]
     slot_off = np.repeat(np.asarray(off, np.int32), hots)
+    if tp == 1:
+        return _bag_sums(jnp.take(table, indices + slot_off, axis=0,
+                                  mode="clip"), hots)
+    slot_per = np.repeat(np.asarray(per, np.int32), hots)
     local = indices - rank * slot_per
     hit = (local >= 0) & (local < slot_per)
-    safe = jnp.where(hit, local + slot_off, 0)
-    rows = jnp.take(table, safe, axis=0, mode="clip")
-    rows = jnp.where(hit[..., None], rows, 0.0)         # (B, ids, dim)
-    starts = itertools.accumulate((0,) + hots[:-1])
-    return jnp.concatenate([rows[:, s:s + m].sum(axis=1)
-                            for s, m in zip(starts, hots)], axis=1)
+    row = jnp.where(hit, local + slot_off, 0)
+    cap = bag_capacity(cfg, b, tp)
+    key = jnp.where(hit, jnp.arange(b * n, dtype=jnp.int32).reshape(b, n),
+                    b * n)
+    pos, pos_row = jax.lax.sort((key.reshape(-1), row.reshape(-1)),
+                                num_keys=1)
+    pos = pos[:cap]
+    rows = jnp.take(table, pos_row[:cap], axis=0, mode="clip")
+    s = pos % n
+    bag = pos // n * t + sum((s >= st).astype(jnp.int32)
+                             for st in itertools.accumulate(hots[:-1]))
+    part = jax.ops.segment_sum(rows, bag, num_segments=b * t,
+                               indices_are_sorted=True).reshape(b, t * dim)
+
+    def masked():
+        with jax.named_scope("dlrm.bag_overflow"):
+            rows = jnp.take(table, row, axis=0, mode="clip")
+            return _bag_sums(jnp.where(hit[..., None], rows, 0.0), hots)
+
+    # Only the swap sits under control flow: a device trace lists a
+    # conditional with the span of the branch it ran, beside that branch's
+    # ops, so a batch that fits runs the bags above and an empty branch.
+    return jax.lax.cond(hit.sum() > cap, masked, lambda: part)
 
 
 def dcnv2_forward(params, indices, dense, ctx: ParCtx, cfg: DLRMConfig):
@@ -249,10 +308,13 @@ def dcnv2_forward(params, indices, dense, ctx: ParCtx, cfg: DLRMConfig):
 
     Device scopes: `dlrm.bag` (the partial bags and the engine's
     reduce-scatter, nested as `engine.reduce_scatter`), `dlrm.bottom<i>`,
-    `dlrm.cross<i>` (cross0 also forms x0) and `dlrm.top<i>`. Counters in
-    the engine's registry, set as the step is traced: ids per query,
-    table rows held per chip, and the reduce-scatter's input bytes per
-    batch on each chip (0 where tp = 1 and no collective runs)."""
+    `dlrm.cross<i>` (cross0 also forms x0) and `dlrm.top<i>`; a batch
+    whose ids overflow a chip's compacted bags runs the masked bags there
+    under `dlrm.bag/dlrm.bag_overflow`. Counters in the engine's
+    registry, set as the step is traced: ids per query, table rows held
+    per chip, rows each chip gathers for a batch's bags (`bag_capacity`),
+    and the reduce-scatter's input bytes per batch on each chip (0 where
+    tp = 1 and no collective runs)."""
     tp = ctx.tp
     b = indices.shape[0]
     _, _, rows_l = table_layout(cfg, tp)
@@ -261,6 +323,7 @@ def dcnv2_forward(params, indices, dense, ctx: ParCtx, cfg: DLRMConfig):
     reg = ctx.engine.metrics
     reg.counter("dlrm.ids_per_query", cfg.ids_per_query)
     reg.counter("dlrm.table_rows_per_chip", rows_l)
+    reg.counter("dlrm.bag_rows_gathered", bag_capacity(cfg, b, tp))
     reg.counter("dlrm.bag_collective_bytes", bag_bytes if tp > 1 else 0)
     with jax.named_scope("dlrm.bag"):
         part = bag_partials(params["tables"], indices, cfg, tp,

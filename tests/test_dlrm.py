@@ -1,6 +1,9 @@
 """Distributed DLRM (paper use case 2) and DLRM-DCNv2 vs single-device
 references."""
 import dataclasses
+import hashlib
+import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -132,6 +135,81 @@ def test_dlrm_dcnv2_row_shards_add_up_to_the_pooled_bags(rng):
     assert np.abs(parts[0][:, t3]).sum() > 0
 
 
+def _masked_partials(table, ids, cfg, tp, rank):
+    """Shard `rank`'s partial bags by gathering every id and masking the
+    ids other shards hold, in numpy."""
+    per, off, _ = dlrm_mod.table_layout(cfg, tp)
+    local = ids - rank * np.repeat(per, cfg.hots)
+    hit = (local >= 0) & (local < np.repeat(per, cfg.hots))
+    rows = table[np.where(hit, local + np.repeat(off, cfg.hots), 0)]
+    rows = np.where(hit[..., None], rows, 0.0)
+    starts = np.cumsum((0,) + cfg.hots[:-1])
+    return np.concatenate([rows[:, s:s + m].sum(axis=1)
+                           for s, m in zip(starts, cfg.hots)], axis=1), \
+        int(hit.sum())
+
+
+@pytest.mark.parametrize("case", ["rank0", "rank1", "rank2", "rank3",
+                                  "overflow", "tp1"])
+def test_dlrm_dcnv2_compacted_bags_match_the_masked_gather(rng, case):
+    """Each shard's compacted bags (batch 256: 3,072 ids, 2,048 slots)
+    equal the masked gather's. A batch whose ids all lie in shard 0's
+    rows puts all 3,072 there, more than the slots hold: it is answered
+    exactly, so the masked path ran. At tp = 1 every id is gathered
+    directly, with no compaction."""
+    tp = 1 if case == "tp1" else 4
+    cfg, _, _, params, ids, _ = _dcnv2_setup(rng, tp=tp, batch=256)
+    per, _, rows_l = dlrm_mod.table_layout(cfg, tp)
+    if case == "overflow":
+        ids = np.concatenate(
+            [rng.integers(0, min(p, r), (256, m))
+             for p, r, m in zip(per, cfg.rows, cfg.hots)],
+            axis=1).astype(np.int32)
+    rank = int(case[-1]) if case.startswith("rank") else 0
+    table = params["tables"][rank * rows_l:(rank + 1) * rows_l]
+    want, hits = _masked_partials(np.asarray(table), ids, cfg, tp, rank)
+    cap = dlrm_mod.bag_capacity(cfg, 256, tp)
+    if case == "overflow":
+        assert hits == ids.size > cap == 2048
+    elif tp > 1:
+        assert 0 < hits <= cap < ids.size
+    else:
+        assert hits == cap == ids.size
+
+    def partials(t, i, r):
+        return dlrm_mod.bag_partials(t, i, cfg, tp, r)
+
+    got = np.asarray(jax.jit(partials)(table, ids, jnp.int32(rank)))
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    prims = {e.primitive.name for e in jax.make_jaxpr(partials)(
+        table, ids, jnp.int32(rank)).jaxpr.eqns}
+    assert ("cond" in prims) == (tp > 1)
+
+
+def test_dlrm_dcnv2_step_with_one_chip_overflowing_matches_reference(rng):
+    """Batch 256 (3,072 ids, 2,048 slots per chip) whose first 192
+    queries hold only ids of shard 0's rows: chip 0 holds more ids than
+    its slots and swaps in the masked bags, the other chips compact, and
+    the served step still matches the reference."""
+    cfg, mesh, ctx, params, ids, dense = _dcnv2_setup(rng, batch=256)
+    per, _, rows_l = dlrm_mod.table_layout(cfg, 4)
+    ids[:192] = np.concatenate(
+        [rng.integers(0, min(p, r), (192, m))
+         for p, r, m in zip(per, cfg.rows, cfg.hots)], axis=1)
+    packed = np.asarray(params["tables"])
+    hits = [_masked_partials(packed[r * rows_l:(r + 1) * rows_l], ids,
+                             cfg, 4, r)[1] for r in range(4)]
+    cap = dlrm_mod.bag_capacity(cfg, 256, 4)
+    assert hits[0] > cap and all(0 < h <= cap for h in hits[1:]), hits
+    out = np.asarray(_dcnv2_step(cfg, mesh, ctx)(params, ids, dense))
+    tables = dlrm_mod.unpack_tables(params["tables"], cfg, 4)
+    ref = np.asarray(dlrm_mod.dlrm_dcnv2_reference(
+        tables, params, jnp.asarray(ids), jnp.asarray(dense), cfg))
+    # the tolerance of test_dlrm_dcnv2_sharded_matches_reference
+    np.testing.assert_allclose(out, ref, atol=1e-5 * np.abs(ref).max(),
+                               rtol=0)
+
+
 def test_dlrm_dcnv2_counts_its_work_when_built(rng):
     cfg, mesh, ctx, params, ids, dense = _dcnv2_setup(rng)
     _dcnv2_step(cfg, mesh, ctx).lower(params, ids, dense)
@@ -140,6 +218,34 @@ def test_dlrm_dcnv2_counts_its_work_when_built(rng):
     assert m.get("dlrm.ids_per_query") == sum(cfg.hots) == 12
     assert m.get("dlrm.table_rows_per_chip") == rows_l == 896
     assert m.get("dlrm.bag_collective_bytes") == 16 * 5 * 8 * 4
+    # 16 x 12 ids: 3/8 of them rounded up to 1,024 is more than all
+    assert m.get("dlrm.bag_rows_gathered") == \
+        dlrm_mod.bag_capacity(cfg, 16, 4) == 16 * 12
+    assert dlrm_mod.bag_capacity(cfg, 256, 4) == 2048
+    assert dlrm_mod.bag_capacity(dlrm_v2(), 2048, 4) == 164_864
+    assert dlrm_mod.bag_capacity(dlrm_v2(), 2048, 1) == 2048 * 214
+
+
+def test_dlrm_dcnv2_bag_ops_class_as_lookup(rng):
+    """Every gather, scatter and sort of the compiled DCNv2 step, the
+    overflow path's too, lies in `dlrm.bag` outside any `engine.*`, so the
+    benchmark's DCNv2 driver classes it as the bags (`lookup`)."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from bench import opclass, scopes
+    from bench.drivers.dlrm_dcnv2_serve import scope_class
+
+    cfg, mesh, ctx, params, ids, dense = _dcnv2_setup(rng, batch=256)
+    text = _dcnv2_step(cfg, mesh, ctx).lower(
+        params, ids, dense).compile().as_text()
+    paths = scopes.scope_map(text)
+    ops = [(ins["name"], ins["opcode"])
+           for comp in opclass.parse_hlo(text)["comps"].values()
+           for ins in comp if ins["opcode"] in ("gather", "scatter", "sort")]
+    assert {"gather", "scatter"} <= {op for _, op in ops}
+    assert {n: paths[n] for n, _ in ops
+            if scope_class(paths[n]) != "lookup"} == {}
+    assert ("dlrm.bag", "dlrm.bag_overflow") in set(paths.values())
 
 
 def test_dlrm_paper_config_path_unchanged(rng):
@@ -165,6 +271,37 @@ def test_dlrm_paper_config_path_unchanged(rng):
     np.testing.assert_array_equal(outs[0], outs[1])
     ref = np.asarray(dlrm_mod.dlrm_reference(params, idx))
     np.testing.assert_allclose(outs[1], ref, atol=1e-3, rtol=1e-3)
+
+
+# sha256 of Table 2's serving step at `reduced()`, lowered (StableHLO,
+# no debug info) on a (1, 1, tp) mesh: the program before the DCNv2 bags
+# were compacted. A change to Table 2's step, the engine's programs
+# included, shows here; one made on purpose updates the digest.
+TABLE2_STEP_SHA256 = {
+    1: "c9425dad87913106d85307aad5b9982f65e7bf32bc17109c10a5a62d53a1ef1e",
+    4: "e1fb63fd503dddb13d5af8d1046b3a9c5e1b791288afde1b455b8350f6741987",
+}
+
+
+@pytest.mark.parametrize("tp", [1, 4])
+def test_dlrm_table2_step_lowers_to_the_same_program(tp):
+    cfg = reduced()
+    mesh = make_mesh((1, 1, tp), ("pod", "data", "model"))
+    ctx = ParCtx(engine=CollectiveEngine(mesh), pcfg=ParallelConfig(),
+                 mesh=mesh)
+
+    def dlrm_serve_step(p, i):
+        return dlrm_mod.dlrm_forward(p, i, ctx, cfg=cfg)
+
+    g = jax.jit(jax.shard_map(
+        dlrm_serve_step, mesh=mesh,
+        in_specs=(dlrm_mod.dlrm_specs(cfg, tp), P(None, None)),
+        out_specs=P(None, None), check_vma=False))
+    text = g.lower(dlrm_mod.dlrm_params(Builder("shape"), cfg, tp),
+                   jax.ShapeDtypeStruct((8, cfg.n_tables), jnp.int32)
+                   ).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        TABLE2_STEP_SHA256[tp]
 
 
 def test_dlrm_v2_config_is_mlperfs():

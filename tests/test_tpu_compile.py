@@ -11,6 +11,7 @@ keeps it until it exits. Keep every such compile in this one file.
 """
 import dataclasses
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -164,6 +165,27 @@ def test_microcode_collectives_compile_on_four_chips(topo, collective):
     assert "collective-permute" in g.lower(x).compile().as_text()
 
 
+def _split_by_conditional(hlo):
+    """Parsed HLO -> (opcodes reached from the entry without entering a
+    `conditional`, names of the gathers, scatters and sorts inside one)."""
+    comps = hlo["comps"]
+    outside, inside, seen = set(), [], set()
+    todo = [(hlo["entry"], False)]
+    while todo:
+        comp, in_cond = todo.pop()
+        if (comp, in_cond) in seen:
+            continue
+        seen.add((comp, in_cond))
+        for ins in comps.get(comp, ()):
+            if in_cond and ins["opcode"] in ("gather", "scatter", "sort"):
+                inside.append(ins["name"])
+            elif not in_cond:
+                outside.add(ins["opcode"])
+            todo += [(c, in_cond or ins["opcode"] == "conditional")
+                     for c in ins["callees"]]
+    return outside, inside
+
+
 def test_dlrm_dcnv2_step_compiles_on_four_chips(topo):
     """DLRM-DCNv2 at the benchmark's cut (the five 40M-row tables at 18M:
     12.06 GB of packed tables per chip) and batch 2048 on a 2x2, with the
@@ -194,6 +216,26 @@ def test_dlrm_dcnv2_step_compiles_on_four_chips(topo):
     assert 12.0e9 < mem.argument_size_in_bytes < TPU_V5E.hbm_bytes, mem
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < TPU_V5E.hbm_bytes, mem
-    assert "collective-permute" in c.as_text()
+    text = c.as_text()
+    assert "collective-permute" in text
     assert ctx.engine.metrics.get("dlrm.bag_collective_bytes") == \
         2048 * 26 * 128 * 4
+    assert ctx.engine.metrics.get("dlrm.bag_rows_gathered") == 164_864
+    # the chip's fusions of the bags' gathers, scatters and sorts (the
+    # overflow path's too) class as the benchmark's `lookup`
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from bench import opclass, scopes
+    from bench.drivers.dlrm_dcnv2_serve import scope_class
+    paths = scopes.scope_map(text)
+    ops = [ins["name"] for comp in opclass.parse_hlo(text)["comps"].values()
+           for ins in comp if ins["opcode"] in ("gather", "scatter", "sort")]
+    assert ops
+    assert {n: paths[n] for n in ops if scope_class(paths[n]) != "lookup"} \
+        == {}
+    # the compacted bags run outside the overflow's `conditional` (whose
+    # span the trace would count beside its body's ops), and only the
+    # masked bags inside it
+    outside, inside = _split_by_conditional(opclass.parse_hlo(text))
+    assert {"sort", "gather"} <= outside
+    assert inside and all("dlrm.bag_overflow" in paths[n] for n in inside)
