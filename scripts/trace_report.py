@@ -5,9 +5,8 @@ Usage:
     python scripts/trace_report.py TRACE.json [--top N] [--json]
 
 Reads the `{"traceEvents": [...]}` file a `Tracer.to_chrome_trace()`
-produced (e.g. `python -m benchmarks.run --quick --trace TRACE.json`, or
-any `simulate_drain` / `MeshMakespan.timeline()` run under
-`telemetry.use(...)`) and prints:
+produced (e.g. any `simulate_drain` / `MeshMakespan.timeline()` run
+under `telemetry.use(...)`) and prints:
 
   * **per-link utilization** — busy seconds per physical-link track on
     the virtual clock, as a fraction of the trace's virtual end;
@@ -19,7 +18,7 @@ any `simulate_drain` / `MeshMakespan.timeline()` run under
   * **control-plane summary** — span/instant counts per name (selector
     choices, compiles + cache hits, retries).
 
-`--json` emits the same summary as one JSON object (CI smoke uses it).
+`--json` emits the same summary as one JSON object.
 Stdlib-only; no repro import needed to read a trace.
 """
 from __future__ import annotations

@@ -3,7 +3,7 @@
 Every assigned architecture is an `ArchConfig` in its own module
 (src/repro/configs/<id>.py); `get_config(name)` resolves them. The
 parallelism/run knobs live in `MeshConfig`/`RunConfig` so the same arch can
-be lowered for smoke tests (1 device), benchmarks (8 virtual devices) and
+be lowered for smoke tests (1 device), tests (8 virtual devices) and
 the production dry-run (512 virtual devices) without edits.
 """
 from __future__ import annotations

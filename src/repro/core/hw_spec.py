@@ -1,8 +1,8 @@
-"""Hardware constants for the roofline model (TPU v5e target).
+"""Hardware constants for the cost model (TPU v5e target).
 
 ACCL+ evaluates on Alveo-U55C + 100 Gb/s Ethernet; our target is a TPU v5e
 pod slice. These constants feed the algorithm selector's alpha-beta cost
-model (core/selector.py) and the roofline analysis (benchmarks/roofline.py).
+model (core/selector.py).
 """
 from __future__ import annotations
 

@@ -12,7 +12,7 @@ cheapest. A user tuning table overrides the model (the paper's
 "configuration parameters"), so deployments can pin choices measured on
 their fabric — without touching any model code.
 
-Protocol model (paper §4.4.3, adapted per DESIGN.md §5):
+Protocol model (paper §4.4.3):
   eager       no handshake; receiver staging copy costs msg/eager_copy_bw.
               Only available while the message fits the Rx-buffer pool.
   rendezvous  +1 handshake RTT; zero-copy delivery.
@@ -564,20 +564,19 @@ class Selector:
         self._note_choice(priced, best, second)
         return best
 
-    # -- tuning-table artifacts (fig12 / EXPERIMENTS round-trips) -----------
+    # -- tuning-table artifacts (round-trips through apply_table) ----------
     DEFAULT_TABLE_SIZES = (1 << 10, 1 << 13, 1 << 17, 1 << 20, 1 << 24,
                            1 << 27)
 
     def table(self, collective: str, comm: Communicator,
               sizes=DEFAULT_TABLE_SIZES, codec: Optional[str] = None,
               elem_bytes: int = 4):
-        """Selection table — the fig12-style artifact for EXPERIMENTS.md.
+        """Selection table: the pick per size bucket.
 
         Each Choice carries the full tuning state for its size bucket:
         algorithm, protocol, chosen segment count, and the codec the
-        pricing assumed (`Choice.compressed`) — so benchmark output and
-        tuning-table round-trips are lossless (see `table_rows` /
-        `apply_table`).
+        pricing assumed (`Choice.compressed`) — so tuning-table
+        round-trips are lossless (see `table_rows` / `apply_table`).
         """
         return {s: self.choose(collective, s, comm, codec=codec,
                                elem_bytes=elem_bytes) for s in sizes}
@@ -585,7 +584,7 @@ class Selector:
     def table_rows(self, collective: str, comm: Communicator,
                    sizes=DEFAULT_TABLE_SIZES, codec: Optional[str] = None,
                    elem_bytes: int = 4) -> list:
-        """`table()` as JSON-ready rows (benchmark / EXPERIMENTS output)."""
+        """`table()` as JSON-ready rows (the `apply_table` format)."""
         rows = []
         for size, c in self.table(collective, comm, sizes, codec,
                                   elem_bytes).items():

@@ -5,7 +5,7 @@ first compiled through `core/program.py`), over explicit per-rank buffers,
 with no jax involved. Used for:
   * algorithm validation (tests compare against numpy oracles),
   * schedule/IR debugging without tracing/compiling,
-  * the latency *model* evaluation in the fig10/fig12 benchmarks.
+  * the offload queue's drain model (`Sequencer.simulate_drain`).
 
 Because both executors interpret one compiled artifact, oracle parity here
 covers the real engine code path (LOOP coalescing, SEG_LOOP segmentation,

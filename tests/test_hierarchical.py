@@ -200,20 +200,31 @@ def test_selector_delegates_degenerate_pod():
     assert c.predicted_s == inner.predicted_s
 
 
-def test_selector_hier_beats_flat_at_bandwidth_sizes():
-    """The hierarchical pick is strictly cheaper than the best flat
-    candidate priced over the same product (the reason it wins)."""
-    comm = _pc(4, 4)
+@pytest.mark.parametrize("nbytes", [1 << 16, 1 << 20, 4 << 20, 16 << 20])
+@pytest.mark.parametrize("pod", [2, 4])
+def test_selector_hier_beats_flat_at_bandwidth_sizes(pod, nbytes):
+    """On a 16-rank (pod x intra) product, from 64 KiB through 16 MiB,
+    the hierarchical pick is strictly cheaper than the best flat
+    candidate (the reason it wins) and moves fewer bytes over DCN."""
+    comm = _pc(pod, 16 // pod)
     sel = Selector()
-    c = sel.choose("allreduce", 1 << 20, comm)
+    c = sel.choose("allreduce", nbytes, comm)
+    assert c.algorithm.startswith("hierarchical:"), c
     # price the best flat candidate by pinning the hierarchical family out
     flat_best = min(
         sel.price_program(
             A.GENERATORS[("allreduce", a)](comm.flat).compile(),
-            "rendezvous", float(1 << 20), comm)
+            "rendezvous", float(nbytes), comm)
         for a in ("ring", "bidi_ring", "recursive_doubling")
     )
     assert c.predicted_s < flat_best
+    # ... and the selector's own flat pick over the all-DCN view, with
+    # its segment ladder
+    flat = sel.choose("allreduce", nbytes, comm.flat)
+    assert c.predicted_s < flat.predicted_s
+    dcn_hier = c.program.fabric_wire_bytes(nbytes, comm)["dcn"]
+    dcn_flat = flat.program.fabric_wire_bytes(nbytes, comm.flat)["dcn"]
+    assert dcn_hier < dcn_flat
 
 
 def test_table_round_trip_with_hierarchical_names():

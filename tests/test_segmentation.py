@@ -2,9 +2,9 @@
 
 Parity: every segmented lowering must be numerics-identical to the
 unsegmented one (segments cut elementwise combines into disjoint pieces,
-so uncompressed paths are bitwise-equal). Model: the pipelined alpha-beta
-prediction must strictly dominate the 1-segment baseline for large
-messages.
+so uncompressed paths are bitwise-equal). Model: for large messages the
+pipelined alpha-beta prediction strictly dominates the 1-segment baseline
+exactly where the compiled program streams.
 """
 import jax
 import jax.numpy as jnp
@@ -14,7 +14,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import CollectiveEngine, Communicator, Selector
 from repro.core import algorithms as A
-from repro.core.engine import _fit_segments
+from repro.core.engine import _fit_segments, _gen_schedule
+from repro.core.program import Stream, StreamChain
 
 
 @pytest.fixture(scope="module")
@@ -295,17 +296,51 @@ def test_fit_segments_divisor_clamp():
     assert _fit_segments(0, 4) == 1
 
 
-@pytest.mark.parametrize("nbytes", [1 << 20, 16 << 20, 256 << 20])
-def test_pipelining_dominates_unsegmented_at_1mib(nbytes):
-    """Acceptance: for >= 1 MiB some k > 1 strictly beats k = 1 (priced
-    on the compiled, stream-fused programs — `Program.cost`)."""
+#: (collective, algorithm, cross-step streams at some k > 1) on 8 ranks:
+#: the rings and the SEL_RANGE-chained recursive halving/doubling stream,
+#: the unrolled trees, the hypercube allreduce and Bruck's masked
+#: all-to-all are SEG_LOOP-only (serialized)
+SEGMENTED_PAIRS = [
+    ("allgather", "ring", True),
+    ("allgather", "recursive_doubling", True),
+    ("allreduce", "ring", True),
+    ("allreduce", "bidi_ring", True),
+    ("allreduce", "halving_doubling", True),
+    ("allreduce", "recursive_doubling", False),
+    ("alltoall", "bruck", False),
+    ("bcast", "binomial_tree", False),
+    ("reduce", "binomial_tree", False),
+    ("reduce", "ring", True),
+    ("reduce_scatter", "recursive_halving", True),
+    ("reduce_scatter", "ring", True),
+]
+
+
+def _streams(prog):
+    return any(isinstance(op, (Stream, StreamChain)) for op in prog.ops)
+
+
+@pytest.mark.parametrize("nbytes", [1 << 20, 4 << 20, 16 << 20, 64 << 20,
+                                    256 << 20])
+@pytest.mark.parametrize("collective,algorithm,streams", SEGMENTED_PAIRS)
+def test_pipelining_dominates_unsegmented_at_1mib(collective, algorithm,
+                                                  streams, nbytes):
+    """Acceptance, split-model form: for >= 1 MiB some k > 1 strictly
+    beats k = 1 EXACTLY where the compiled program cross-step streams
+    (`STREAM`/`STREAM_CHAIN`); a serialized program's best count is 1.
+    Priced on the compiled, stream-fused programs (`Program.cost`) over
+    the selector's segment ladder."""
     comm = Communicator(axis="x", size=8)
-    for gen in (A.ring_allreduce, A.ring_reduce_scatter, A.ring_allgather):
-        sched = gen(comm)
-        t1 = sched.compile(segments=1).cost(nbytes, comm)
-        best = min(sched.compile(segments=k).cost(nbytes, comm)
-                   for k in (2, 4, 8, 16, 32))
-        assert best < t1, (gen.__name__, nbytes)
+    sched = _gen_schedule(collective, algorithm, comm)
+    progs = {k: sched.compile(segments=k)
+             for k in Selector.DEFAULT_SEGMENT_CANDIDATES}
+    assert not _streams(progs[1])
+    assert any(_streams(p) for p in progs.values()) == streams
+    times = {k: p.cost(nbytes, comm) for k, p in progs.items()}
+    if streams:
+        assert min(times.values()) < times[1]
+    else:
+        assert min(times, key=times.get) == 1
 
 
 def test_program_cost_segment_model_shape():

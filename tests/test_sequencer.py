@@ -333,16 +333,17 @@ def test_cost_terms_decomposes_cost(engines):
                                            rel=1e-12)
 
 
+@pytest.mark.parametrize("m", [2, 4, 8])
 def test_makespan_of_independent_queue_strictly_below_serial(engines,
-                                                             rng):
-    """Acceptance: a queue of >= 4 independent same-axis collectives
+                                                             rng, m):
+    """Acceptance: a queue of m > 1 independent same-axis collectives
     prices strictly below the sum of blocking Program.costs, and the
     simulator-executed drain is bitwise-equal to the blocking sequence."""
     eng = engines
     n = 8
     seq = Sequencer(eng)
     feeds, reqs = {}, []
-    for _ in range(4):
+    for _ in range(m):
         x = np.zeros((1 << 16,), np.float32)  # > coalesce cap: no bucket
         r = seq.issue("allreduce", x, "x")
         feeds[r] = [rng.normal(size=(1 << 16,)).astype(np.float32)
@@ -355,7 +356,7 @@ def test_makespan_of_independent_queue_strictly_below_serial(engines,
     # the serial reference really is the sum of blocking Program.costs
     choice = eng.selector.choose("allreduce", 4 << 16, comm, elem_bytes=4)
     assert serial == pytest.approx(
-        4 * choice.program.cost(4 << 16, comm), rel=1e-12)
+        m * choice.program.cost(4 << 16, comm), rel=1e-12)
     assert makespan < serial
     assert makespan >= choice.program.cost(4 << 16, comm)  # >= one call
 
@@ -418,10 +419,12 @@ def test_empty_and_single_request_makespan(engines):
     eng = engines
     seq = Sequencer(eng)
     assert seq.makespan("x") == 0.0
-    seq.issue("allreduce", np.zeros((1 << 16,), np.float32), "x")
-    assert seq.makespan("x") == pytest.approx(seq.serial_cost("x"),
-                                              rel=1e-9)
-    seq.clear()
+    for elems in (1 << 16, 1 << 10):  # above and below the coalesce cap
+        seq.issue("allreduce", np.zeros((elems,), np.float32), "x")
+        assert not any(it.coalesced for it in seq.plan("x"))
+        assert seq.makespan("x") == pytest.approx(seq.serial_cost("x"),
+                                                  rel=1e-9)
+        seq.clear()
 
 
 def test_simulate_drain_honours_op_and_root_under_auto(engines, rng):

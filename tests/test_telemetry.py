@@ -36,9 +36,10 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.core import (
-    CollectiveEngine, FaultPlan, FaultyTransport, MeshMakespan, PricingEnv,
-    Selector, TIERS, TransportTimeout, telemetry,
+    CollectiveEngine, Communicator, FaultPlan, FaultyTransport, MeshMakespan,
+    PricingEnv, Selector, TIERS, TransportTimeout, telemetry,
 )
+from repro.core import algorithms as A
 from repro.core.sequencer import Request, Sequencer
 
 _ROOT = pathlib.Path(__file__).resolve().parent.parent
@@ -590,6 +591,44 @@ def test_tracing_is_read_only_for_selection_and_pricing(eng8):
         assert seq.makespan("x") == ref_makespan
         assert MeshMakespan.of(seq).report() == ref_report
     seq.clear()
+
+
+def test_tracing_is_read_only_across_the_priced_surface(eng8, eng222):
+    """The wider pricing surface under an enabled tracer: every segment
+    count of a streamed program, a hierarchical pick over a DCN product,
+    a makespan under a lossy reliability tier, and two queues composed
+    across disjoint fabrics all price bitwise-equal, and the tracer saw
+    the selection and composition (the instrumentation is live)."""
+    comm = eng8.comm("x")
+    product = Communicator(axis="pod", size=16, is_dcn=True).factor(4)
+    tier = PricingEnv(comm=comm, tier=TIERS["tcp-like"], drop_prob=0.05)
+
+    def price():
+        ring = A.ring_allreduce(comm)
+        ladder = [ring.compile(segments=k).cost(16 << 20, comm)
+                  for k in Selector.DEFAULT_SEGMENT_CANDIDATES]
+        hier = Selector().choose("allreduce", 4 << 20, product)
+        seq = Sequencer(eng8)
+        _fill(seq, "x", 1 << 20)
+        lossy = seq.makespan("x", env=tier)
+        seq.clear()
+        mm, seqs = MeshMakespan(), []
+        for axis in ("data", "pod"):
+            s = Sequencer(eng222)
+            _fill(s, axis, 1 << 20)
+            mm.add(s, axis)
+            seqs.append(s)
+        total = mm.total()
+        for s in seqs:
+            s.clear()
+        return (ladder, hier.predicted_s, hier.algorithm, hier.segments,
+                lossy, total)
+
+    ref = price()
+    with telemetry.use(telemetry.Tracer()) as tr:
+        traced = price()
+    assert traced == ref
+    assert tr._events, "tracer recorded nothing: instrumentation gone?"
 
 
 def test_tracing_is_read_only_for_simulate_drain(eng8):
